@@ -57,8 +57,8 @@ class FederationSpec:
     kernel_backend: str = "auto"    # clip+noise kernel backend
     #   ("pallas" | "interpret" | "ref" | "auto"): every engine's Eq.-7a
     #   clip+noise step runs through kernels.dispatch get_kernel(
-    #   "dp_clip_noise") on this backend; "auto" probes the installed
-    #   jax/pallas and falls back to the jnp oracle
+    #   "dp_clip_noise") on this backend; "auto" is the Mosaic kernel on
+    #   a TPU (or an error there) and the jnp oracle elsewhere
 
     # -- aggregation pipeline (Eq. 7b boundary; core/aggregation.py) -------
     participation: float = 1.0      # fraction q in (0,1], or an int count of

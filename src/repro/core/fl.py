@@ -296,9 +296,8 @@ def make_resident_chunked_round(round_fn: Callable, *, n_clients: int,
     from repro.kernels.dispatch import resolve_backend
     from repro.kernels.ops import cohort_gather, cohort_scatter
 
-    # resolve eagerly, at build time: capability probes cannot run inside
-    # the traced scan body (dispatch's trace-state guard would silently
-    # demote auto to ref there)
+    # resolve eagerly, at build time: capability probes run real arrays and
+    # cannot run inside the traced scan body
     kernel_backend = resolve_backend("cohort_gather_scatter",
                                      kernel_backend or "auto")
 

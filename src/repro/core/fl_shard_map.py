@@ -45,22 +45,18 @@ from repro.utils.tree import tree_broadcast_axis0
 
 
 def _shard_map(f, mesh, in_specs, out_specs, auto=frozenset()):
-    """shard_map across jax versions: jax.shard_map (>=0.6, check_vma) or
-    jax.experimental.shard_map (0.4.x, check_rep). Replication checking is
-    disabled either way — the out_specs deliberately mix P(client) and P().
+    """``jax.shard_map`` with replication checking off (the out_specs
+    deliberately mix P(client) and P()).
 
     ``auto`` names mesh axes left under GSPMD control (partial-manual mode):
     the body is manual over the remaining axes only, and operands keep
     whatever sharding the partitioner gave them along the auto axes. The
     mesh_2d engine (repro.mesh) runs with ``auto={"model"}`` so model
     tensors stay sharded straight through the per-client round body."""
-    kw = {"auto": frozenset(auto)} if auto else {}
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False, **kw)
+    manual = frozenset(mesh.axis_names) - frozenset(auto)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=manual,
+                         check_vma=False)
 
 
 def make_shard_map_round(loss_fn: Callable, optimizer: Optimizer,

@@ -8,8 +8,8 @@ flash attention, RWKV6 WKV scan, and Mamba2 SSD chunk scan.
 Kernel backends
 ---------------
 Every kernel is registered in :mod:`repro.kernels.dispatch` with its
-pure-jnp oracle from :mod:`repro.kernels.ref` as a guaranteed-correct
-fallback, and is selected by name + backend:
+pure-jnp oracle from :mod:`repro.kernels.ref` (the parity reference and
+the off-TPU default), and is selected by name + backend:
 
     from repro.kernels import get_kernel
     y, norm = get_kernel("dp_clip_noise")(g, noise, clip_norm, sigma)
@@ -20,12 +20,16 @@ backend    meaning
 "pallas"   Mosaic-compiled Pallas (TPU only)
 "interpret" ``pallas_call(interpret=True)`` — kernel body as jax ops (CPU)
 "ref"      the pure-jnp oracle; always available
-"auto"     ``KERNEL_BACKEND`` env var if set, else the best backend whose
-           cached capability probe passes (pallas > interpret > ref)
+"auto"     ``KERNEL_BACKEND`` env var if set, else "pallas" on a TPU (or
+           an error carrying the probe's exception) and "ref" elsewhere
 ========== ==============================================================
 
-The capability probe runs each kernel once on tiny shapes against its
-oracle, so a drifted jax/pallas API degrades to "ref" instead of erroring.
+On a TPU the capability probe runs each kernel once on small tile-legal
+shapes against its oracle; a kernel that fails it raises, so a chip run
+never quietly runs interpret mode or the oracle. ``rwkv6_scan`` and
+``mamba2_ssd`` are refused by the TPU compiler today (their block shapes
+break the (8, 128) tiling), so ``auto`` raises for them on a TPU; the
+model code does not call them.
 The training hot path selects its backend declaratively through
 ``FederationSpec(kernel_backend=...)``; :mod:`repro.kernels.ops` carries
 the plain-function wrappers. ``register_kernel`` adds new kernels without

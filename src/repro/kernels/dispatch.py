@@ -4,23 +4,22 @@ Mirrors the round-engine registry of ``repro.api.engines`` one layer down:
 every compute kernel is registered under a name with
 
   * its Pallas implementation (accepting an ``interpret`` kwarg), and
-  * its pure-jnp oracle from :mod:`repro.kernels.ref` — guaranteed correct
-    on any jax, so the suite degrades gracefully instead of erroring when
-    the installed jax/pallas API drifts.
+  * its pure-jnp oracle from :mod:`repro.kernels.ref` — the reference the
+    parity tests compare against, and the off-TPU default.
 
 Backends
 --------
 ``"pallas"``     Mosaic-compiled Pallas (requires a TPU backend).
 ``"interpret"``  ``pallas_call(interpret=True)`` — same kernel body executed
-                 as jax ops; the CPU/CI path.
+                 as jax ops; the CPU rehearsal of the TPU kernel.
 ``"ref"``        the pure-jnp oracle; always available.
-``"auto"``       resolve at first use: the ``KERNEL_BACKEND`` environment
-                 variable if set, else the best backend whose cached
-                 capability probe passes (pallas > interpret > ref).
+``"auto"``       the ``KERNEL_BACKEND`` environment variable if set; else
+                 ``"pallas"`` on a TPU and ``"ref"`` everywhere else.
 
-A capability probe runs the registered smoke test (tiny shapes, allclose vs
-the oracle) once per (kernel, backend) and caches the verdict, so a drifted
-Pallas API costs one failed probe instead of a red suite.
+There is no fallback on the chip: on a TPU, ``auto`` runs the kernel's
+capability probe (a small tile-legal call checked against the oracle) once
+and resolves to ``"pallas"``, or raises with the probe's own exception
+attached. It never quietly demotes to ``"interpret"`` or ``"ref"`` there.
 
 Usage::
 
@@ -101,16 +100,27 @@ def _disabled_backends() -> frozenset[str]:
 
 
 @functools.lru_cache(maxsize=None)
-def _probe_verdict(name: str, backend: str) -> bool:
-    """The cached probe run. Only ever called from a clean trace state —
-    see backend_works."""
+def probe_error(name: str, backend: str) -> Exception | None:
+    """Run ``name``'s capability probe on ``backend`` once per process.
+
+    Returns None when the probe passes, else the exception it raised (an
+    oracle mismatch is reported as an ``AssertionError``). The probe runs
+    real arrays, so it must not run under a jit trace: engine builders
+    resolve their backends eagerly, at build time
+    (``clipping.make_dp_grad_fn``, ``aggregation.make_compressor``,
+    ``fl.make_resident_chunked_round``).
+    """
     entry = _entry(name)
     if entry.probe is None:
-        return False
+        return LookupError(f"kernel {name!r} registers no capability probe")
     try:
-        return bool(entry.probe(_bind(entry, backend)))
-    except Exception:
-        return False
+        ok = entry.probe(_bind(entry, backend))
+    except Exception as exc:          # reported to the caller, not dropped
+        return exc
+    if not ok:
+        return AssertionError(f"{name!r} on {backend!r} disagrees with its "
+                              f"oracle on the probe inputs")
+    return None
 
 
 def backend_works(name: str, backend: str) -> bool:
@@ -119,17 +129,8 @@ def backend_works(name: str, backend: str) -> bool:
     "ref" is always True. Backends named in ``KERNEL_DISPATCH_DISABLE``
     read as unavailable without probing (oracle-only rehearsal). "pallas"
     (Mosaic-compiled) additionally requires a TPU default backend before
-    the probe is even attempted. Any exception from the probe — the
-    drifted-API AttributeErrors included — reads as "unavailable", never
-    as a test failure.
-
-    Probes cannot run while an outer jax trace is active (the pallas smoke
-    test would capture ambient tracers and spuriously fail); if the first
-    resolution happens mid-trace, answer "unavailable" for that call
-    WITHOUT caching the verdict, so a later eager resolution still probes
-    for real. Engine builders resolve their backends eagerly at build time
-    (clipping.make_dp_grad_fn, aggregation.make_compressor), so the hot
-    path never takes this fallback.
+    the probe is even attempted. :func:`probe_error` keeps the reason of a
+    failed probe.
     """
     if backend == "ref":
         return True
@@ -140,14 +141,11 @@ def backend_works(name: str, backend: str) -> bool:
         return False
     if backend == "pallas" and jax.default_backend() != "tpu":
         return False
-    if not jax.core.trace_state_clean():
-        return False                   # uncached: retry eagerly later
-    return _probe_verdict(name, backend)
+    return probe_error(name, backend) is None
 
 
-# probe-cache reset for tests/tooling (the cache moved to _probe_verdict
-# when the trace-state guard landed; keep the historic reset point)
-backend_works.cache_clear = _probe_verdict.cache_clear
+# probe-cache reset for tests/tooling
+backend_works.cache_clear = probe_error.cache_clear
 
 
 def available_backends(name: str) -> tuple[str, ...]:
@@ -159,17 +157,17 @@ def available_backends(name: str) -> tuple[str, ...]:
 def resolve_backend(name: str, backend: str = "auto") -> str:
     """Map ``backend="auto"`` to a concrete backend for this process.
 
-    Resolution order: an explicit non-auto argument wins untouched (callers
-    get the real error if they force a broken backend); else the
-    ``KERNEL_BACKEND`` env var if set; else the best probed backend for
-    this process's default jax backend. "Best" is platform-aware: on TPU,
-    ``pallas > interpret > ref``; everywhere else ``interpret`` is ranked
-    BELOW the jnp oracle — ``pallas_call(interpret=True)`` executes the
-    kernel body element-block by element-block as jax ops, ~100x slower
-    than the fused oracle on CPU (measured in benchmarks/throughput.py).
-    Interpret mode is a correctness rehearsal, not a fast path; it stays
-    reachable explicitly (``backend="interpret"`` /
-    ``KERNEL_BACKEND=interpret``) and via the parity suites.
+    An explicit non-auto argument wins untouched (callers get the real
+    error if they force a broken backend); else the ``KERNEL_BACKEND`` env
+    var if set; else ``"pallas"`` on a TPU and ``"ref"`` elsewhere. Off the
+    TPU the jnp oracle is the fast path: ``pallas_call(interpret=True)``
+    executes the kernel body block by block as jax ops, ~100x slower on CPU
+    (measured in benchmarks/throughput.py), so interpret mode stays an
+    explicit choice (``backend="interpret"`` / ``KERNEL_BACKEND=interpret``)
+    for the parity suites.
+
+    On a TPU the Pallas kernel is the only answer: if its probe fails, this
+    raises ``RuntimeError`` chained to the probe's exception.
     """
     if backend not in KERNEL_BACKENDS:
         raise ValueError(f"backend must be one of {KERNEL_BACKENDS}, "
@@ -182,12 +180,19 @@ def resolve_backend(name: str, backend: str = "auto") -> str:
             raise ValueError(f"${KERNEL_BACKEND_ENV}={env!r} is not one of "
                              f"{KERNEL_BACKENDS}")
         return env
-    candidates = (("pallas", "interpret") if jax.default_backend() == "tpu"
-                  else ("pallas",))       # off-TPU: ref outranks interpret
-    for candidate in candidates:
-        if backend_works(name, candidate):
-            return candidate
-    return "ref"
+    if jax.default_backend() != "tpu":
+        return "ref"
+    if backend_works(name, "pallas"):
+        return "pallas"
+    if "pallas" in _disabled_backends():
+        raise RuntimeError(f"kernel {name!r}: pallas is disabled by "
+                           f"${KERNEL_DISABLE_ENV} on a TPU")
+    entry = _entry(name)
+    if entry.pallas_fn is None:
+        raise RuntimeError(f"kernel {name!r} has no pallas implementation "
+                           f"to run on a TPU")
+    raise RuntimeError(f"kernel {name!r} failed its pallas probe on this "
+                       f"TPU") from probe_error(name, "pallas")
 
 
 def get_kernel(name: str, backend: str = "auto") -> Callable:
@@ -203,8 +208,9 @@ def get_kernel(name: str, backend: str = "auto") -> Callable:
 # built-in kernels: oracle adapters + smoke probes
 # ---------------------------------------------------------------------------
 # Adapters give every backend one signature: the oracle swallows the Pallas
-# tuning kwargs. Probes run tiny shapes through the bound impl and compare
-# against the oracle — cheap enough to pay once per process.
+# tuning kwargs. Probes run small shapes that the TPU tiling accepts through
+# the bound impl and compare against the oracle — cheap enough to pay once
+# per process.
 
 def _close(a, b, tol=1e-4) -> bool:
     return all(bool(jnp.allclose(jnp.asarray(x, jnp.float32),
@@ -218,9 +224,9 @@ def _dp_clip_noise_oracle(g, noise, clip_norm, sigma, **_tuning):
 
 
 def _dp_clip_noise_probe(impl) -> bool:
-    g = jnp.linspace(-2.0, 3.0, 37, dtype=jnp.float32)
-    noise = jnp.ones((37,), jnp.float32)
-    got = impl(g, noise, 1.0, 0.25, block=16)
+    g = jnp.linspace(-2.0, 3.0, 5000, dtype=jnp.float32)
+    noise = jnp.ones((5000,), jnp.float32)
+    got = impl(g, noise, 1.0, 0.25)
     return _close(got, _ref.dp_clip_noise_ref(g, noise, 1.0, 0.25))
 
 
@@ -229,9 +235,9 @@ def _quantize_decompress_oracle(x, u, bits, **_tuning):
 
 
 def _quantize_decompress_probe(impl) -> bool:
-    x = jnp.linspace(-3.0, 2.0, 41, dtype=jnp.float32)
-    u = jax.random.uniform(jax.random.PRNGKey(3), (41,), jnp.float32)
-    got = impl(x, u, 4, block=16)
+    x = jnp.linspace(-3.0, 2.0, 5000, dtype=jnp.float32)
+    u = jax.random.uniform(jax.random.PRNGKey(3), (5000,), jnp.float32)
+    got = impl(x, u, 4)
     return _close(got, _ref.quantize_decompress_ref(x, u, 4))
 
 
@@ -241,8 +247,9 @@ def _flash_attention_oracle(q, k, v, *, causal=True, window=0, **_tuning):
 
 def _flash_attention_probe(impl) -> bool:
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    q, k, v = (jax.random.normal(kk, (1, 1, 8, 8), jnp.float32) for kk in ks)
-    got = impl(q, k, v, block_q=8, block_k=8)
+    q, k, v = (jax.random.normal(kk, (1, 2, 256, 64), jnp.float32)
+               for kk in ks)
+    got = impl(q, k, v, block_q=128, block_k=128)
     return _close(got, _ref.flash_attention_ref(q, k, v))
 
 
@@ -280,9 +287,9 @@ def _cohort_gather_scatter_oracle(cache, slots, rows=None, **_tuning):
 
 
 def _cohort_gather_scatter_probe(impl) -> bool:
-    cache = jnp.arange(9 * 5, dtype=jnp.float32).reshape(9, 5)
+    cache = jnp.arange(9 * 1024, dtype=jnp.float32).reshape(9, 1024)
     slots = jnp.asarray([7, 0, 4], jnp.int32)
-    rows = -jnp.arange(3 * 5, dtype=jnp.float32).reshape(3, 5)
+    rows = -jnp.arange(3 * 1024, dtype=jnp.float32).reshape(3, 1024)
     got_g = impl(cache, slots)
     got_s = impl(cache, slots, rows)
     return (_close(got_g, _ref.cohort_gather_scatter_ref(cache, slots))

@@ -1,4 +1,4 @@
-"""Flash attention Pallas kernel (TPU target; validated interpret=True).
+"""Flash attention Pallas kernel (TPU target).
 
 Grid: (batch*heads, n_q_blocks). Each program streams kv blocks for one q
 tile with the online-softmax recurrence; running max/denominator/accumulator
@@ -12,7 +12,7 @@ bq, bk, hd are multiples of 128 (hd=128 for most assigned archs).
 kv streaming uses the ref-indexing API (``ref[0, pl.dslice(...), :]``) —
 the tuple-index ``pl.load`` form was dropped upstream. Selected through
 ``repro.kernels.dispatch`` (backend "pallas"/"interpret"), with
-``ref.flash_attention_ref`` as the registered oracle fallback.
+``ref.flash_attention_ref`` as the registered oracle.
 """
 from __future__ import annotations
 
@@ -67,7 +67,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, bq, bk, s, window, scale):
                                              "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
+                    interpret: bool = False):
     """q/k/v (B, H, S, hd) (GQA pre-expanded). Returns (B, H, S, hd)."""
     assert causal, "only causal supported (decoder stacks)"
     b, h, s, hd = q.shape

@@ -1,4 +1,8 @@
-"""Mamba2 SSD chunk-scan Pallas kernel (TPU target; validated interpret=True).
+"""Mamba2 SSD chunk-scan Pallas kernel (TPU target).
+
+The TPU compiler refuses this kernel today: its (1, 1, chunk) ``dt``
+block breaks the (8, 128) tiling rule. It runs in interpret mode only, and
+``auto`` raises for it on a TPU. The model code does not call it.
 
 Grid: (B*H, n_chunks) with the chunk axis sequential ("arbitrary" dimension
 semantics on TPU) so the (P, N) SSM state lives in VMEM scratch and is
@@ -65,7 +69,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, sfin_ref, s_ref):
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def mamba2_ssd(x, dt, a, b_in, c_in, *, chunk: int = 128,
-               interpret: bool = True):
+               interpret: bool = False):
     """x (B,S,H,P); dt (B,S,H) post-softplus; a (H,); b/c (B,S,N).
     Returns (y (B,S,H,P), final state (B,H,P,N))."""
     bsz, s, h, p = x.shape

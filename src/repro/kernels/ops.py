@@ -2,10 +2,10 @@
 
 Every wrapper resolves its implementation via
 :func:`repro.kernels.dispatch.get_kernel` (``backend="auto"`` by default):
-Mosaic-compiled Pallas on TPU, ``interpret=True`` Pallas on CPU, and the
-pure-jnp oracle when the installed jax/pallas API cannot run the kernel —
-so the same call sites work in this CPU container, on the target hardware,
-and on a drifted jax without erroring.
+Mosaic-compiled Pallas on a TPU — or an error there, never a silent
+substitute — and the pure-jnp oracle everywhere else. ``backend=
+"interpret"`` runs the Pallas body as jax ops, the CPU rehearsal of the
+TPU kernel.
 """
 from __future__ import annotations
 

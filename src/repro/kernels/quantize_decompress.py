@@ -7,8 +7,11 @@ in dense arrays; see :mod:`repro.core.aggregation`). Fusing the round trip
 means the update streams through VMEM once instead of materializing the
 integer codes in HBM.
 
-Two kernels with explicit VMEM BlockSpec tiling, mirroring dp_clip_noise:
-  1. ``_absmax_kernel``: per-tile max(|x|) (grid reduction -> scale).
+Two kernels over the ``(rows, 128)`` lane layout of dp_clip_noise, with
+``(block_rows, 128)`` VMEM blocks and the scale in SMEM:
+  1. a partial reduction (``dp_clip_noise.partial_reduce``): per-block
+     max(|x|), folded to one ``(8, 128)`` tile per grid step (grid
+     reduction -> scale).
   2. ``_quant_kernel``:  y = sign(x) * floor(|x|/scale + u) * scale,
      streaming tiles; ``u ~ U[0,1)`` drives the stochastic rounding.
 
@@ -23,13 +26,13 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from repro.kernels.dp_clip_noise import DEFAULT_BLOCK, _pad_to
-
-
-def _absmax_kernel(x_ref, out_ref):
-    out_ref[0] = jnp.max(jnp.abs(x_ref[...].astype(jnp.float32)))
+from repro.kernels.dp_clip_noise import (
+    DEFAULT_BLOCK,
+    partial_reduce,
+    scaled_call,
+    tile_layout,
+)
 
 
 def _quant_kernel(scale_ref, x_ref, u_ref, out_ref):
@@ -42,40 +45,24 @@ def _quant_kernel(scale_ref, x_ref, u_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("bits", "block", "interpret"))
 def quantize_decompress(x, u, bits: int, *, block: int = DEFAULT_BLOCK,
-                        interpret: bool = True):
+                        interpret: bool = False):
     """x, u: flat (N,) arrays (u ~ U[0,1)). Returns (dequantized (N,), scale).
 
     ``scale = max|x| / (2**bits - 1)`` is the uniform quantization step the
     wire format would ship alongside the integer codes; levels fall in
     [0, 2**bits - 1] so the codes genuinely fit ``bits`` bits. All-zero
-    inputs round-trip to zeros (scale floored away from 0).
+    inputs round-trip to zeros (scale floored away from 0). Padding and
+    the last block's overhang count as zeros, so they never raise the
+    absmax.
     """
-    orig_dtype = x.dtype
     levels = (1 << bits) - 1
-    xp, n = _pad_to(x, block)
-    up, _ = _pad_to(u, block)
-    grid = (xp.shape[0] // block,)
-
-    partial = pl.pallas_call(
-        _absmax_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((block,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((1,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((grid[0],), jnp.float32),
-        interpret=interpret,
-    )(xp)
+    n = x.shape[0]
+    x2, block_rows = tile_layout(x, block)
+    u2, _ = tile_layout(u, block)
+    partial = partial_reduce(x2, block_rows, jnp.abs, jnp.max,
+                             interpret=interpret)
     scale = jnp.maximum(jnp.max(partial), 1e-30) / levels
 
-    out = pl.pallas_call(
-        _quant_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda i: (0,)),      # scale (broadcast)
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-        ],
-        out_specs=pl.BlockSpec((block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((xp.shape[0],), orig_dtype),
-        interpret=interpret,
-    )(scale[None].astype(jnp.float32), xp, up)
-    return out[:n], scale
+    out = scaled_call(_quant_kernel, scale[None], x2, u2,
+                      block_rows=block_rows, interpret=interpret)
+    return out.reshape(-1)[:n], scale

@@ -1,4 +1,8 @@
-"""RWKV6 WKV recurrence Pallas kernel (TPU target; validated interpret=True).
+"""RWKV6 WKV recurrence Pallas kernel (TPU target).
+
+The TPU compiler refuses this kernel today: its (1, hd) ``u`` block
+breaks the (8, 128) tiling rule. It runs in interpret mode only, and
+``auto`` raises for it on a TPU. The model code does not call it.
 
 Grid: (B, H). Each program owns one head's (hd x hd) state in VMEM and walks
 the sequence with a fori_loop:
@@ -10,7 +14,7 @@ Per-timestep rows are read/written with the ref-indexing API
 (``ref[0, 0, pl.dslice(t, 1), :]``) — the tuple-index ``pl.load``/``pl.store``
 form was dropped upstream. Selected through ``repro.kernels.dispatch``
 (backend "pallas"/"interpret"), with ``ref.rwkv6_scan_ref`` as the
-registered oracle fallback.
+registered oracle.
 """
 from __future__ import annotations
 
@@ -45,7 +49,7 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sout_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def rwkv6_scan(r, k, v, w, u, s0=None, *, interpret: bool = True):
+def rwkv6_scan(r, k, v, w, u, s0=None, *, interpret: bool = False):
     """r/k/v/w (B, H, S, hd); u (H, hd); s0 (B, H, hd, hd) or None.
     Returns (y (B, H, S, hd), final state (B, H, hd, hd))."""
     b, h, s, hd = r.shape

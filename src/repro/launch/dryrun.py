@@ -277,7 +277,8 @@ def mesh_report(archs, n_clients: int, n_devices: int,
     )
     from repro.optim import sgd
 
-    budget = device_memory_budget(default=device_mem_bytes)
+    # the fake CPU devices stand for the described v5e target
+    budget = device_memory_budget(default=device_mem_bytes or HBM_PER_CHIP)
     opt = sgd(0.1)
     rows = []
     for arch in archs:

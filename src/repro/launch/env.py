@@ -37,16 +37,26 @@ Because ``LD_PRELOAD`` and ``XLA_FLAGS`` must be set before the process
 interpreter with the profile environment; the re-exec is guarded by
 ``REPRO_ENV_PROFILE_APPLIED=1`` so it happens exactly once.
 :func:`profile_env` is the pure (testable) computation of the env delta.
+
+:func:`enable_compile_cache` turns on JAX's persistent compilation cache
+for the entry points (``chip_smoke.py``, ``launch/train``, ``launch/serve``)
+— called from their ``main``, never at import.
 """
 from __future__ import annotations
 
 import os
 import sys
+from pathlib import Path
 from typing import Mapping
 
 ENV_PROFILES = ("none", "host", "cpu-mesh")
 
 _APPLIED_VAR = "REPRO_ENV_PROFILE_APPLIED"
+
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+# a fixed directory inside the checkout (git-ignored): the cache key holds
+# the path, so a directory that moved between runs would never hit
+DEFAULT_COMPILE_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 # distro locations of tcmalloc, preferred first (full > minimal)
 TCMALLOC_PATHS = (
@@ -136,3 +146,18 @@ def apply_env_profile(profile: str | None, *,
     env[_APPLIED_VAR] = "1"
     os.execvpe(sys.executable, [sys.executable] + sys.argv, env)
     raise AssertionError("unreachable: execvpe does not return")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing is set here. Otherwise the cache goes to the fixed in-checkout
+    :data:`DEFAULT_COMPILE_CACHE`. Call before the first compile.
+    """
+    env = os.environ.get(COMPILE_CACHE_ENV)
+    if env:
+        return env
+    import jax
+    jax.config.update("jax_compilation_cache_dir", str(DEFAULT_COMPILE_CACHE))
+    return str(DEFAULT_COMPILE_CACHE)

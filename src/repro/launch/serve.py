@@ -174,10 +174,12 @@ def main(argv=None):
     ap.add_argument("--fl-checkpoint", default=None,
                     help="serve the aggregated model of a repro.api "
                          "save_state checkpoint instead of random init")
-    from repro.launch.env import add_env_profile_args, apply_env_profile
+    from repro.launch.env import (add_env_profile_args, apply_env_profile,
+                                  enable_compile_cache)
     add_env_profile_args(ap)
     args = ap.parse_args(argv)
     apply_env_profile(args.env_profile, host_devices=args.host_devices)
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.smoke:

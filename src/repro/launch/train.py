@@ -53,7 +53,11 @@ from repro.asyncfl import (
     train_async,
 )
 from repro.configs import get_arch, smoke_variant
-from repro.launch.env import add_env_profile_args, apply_env_profile
+from repro.launch.env import (
+    add_env_profile_args,
+    apply_env_profile,
+    enable_compile_cache,
+)
 from repro.population import (
     HeterogeneousCohort,
     init_population_state,
@@ -273,6 +277,7 @@ def main(argv=None):
     ap.add_argument("--save", default=None)
     args = ap.parse_args(argv)
     apply_env_profile(args.env_profile, host_devices=args.host_devices)
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.smoke:

@@ -25,7 +25,6 @@ sharding_rules=...)``.
 """
 from repro.mesh.engine import default_param_specs, make_mesh_2d_round
 from repro.mesh.placement import (
-    DEFAULT_DEVICE_MEM_BYTES,
     choose_engine,
     default_mesh_shape,
     device_memory_budget,
@@ -35,7 +34,6 @@ from repro.mesh.placement import (
 )
 
 __all__ = [
-    "DEFAULT_DEVICE_MEM_BYTES",
     "choose_engine",
     "default_mesh_shape",
     "default_param_specs",

@@ -147,6 +147,26 @@ def _unpad_rows(tree, n: int):
     return jax.tree.map(lambda x: x[:n], tree)
 
 
+def _refuse_mosaic_under_model_axis(cfg: FLConfig, pipeline) -> None:
+    """XLA cannot partition a Mosaic kernel over the auto model axis ("Mosaic
+    kernels cannot be automatically partitioned"), so a model-sharded
+    mesh_2d round takes the jnp clip+noise and compressor: say so when the
+    round is built, not as a compile error on the chip."""
+    from repro.kernels.dispatch import resolve_backend
+    kernels = []
+    if cfg.kernel_backend is not None:
+        kernels.append(("dp_clip_noise", cfg.kernel_backend))
+    compressor = getattr(pipeline, "compressor", None)
+    if getattr(compressor, "kernel_backend", None) is not None:
+        kernels.append(("quantize_decompress", compressor.kernel_backend))
+    for name, backend in kernels:
+        if resolve_backend(name, backend) == "pallas":
+            raise ValueError(
+                f"mesh_2d with a model axis cannot run the Mosaic {name} "
+                f"kernel: XLA cannot partition it over the model axis. Use "
+                f"kernel_backend='ref' (or a (C, 1) mesh).")
+
+
 def make_mesh_2d_round(loss_fn: Callable, optimizer: Optimizer,
                        cfg: FLConfig, mesh: Mesh, *, rules=None,
                        topology: str = "full_average", pipeline=None,
@@ -186,6 +206,7 @@ def make_mesh_2d_round(loss_fn: Callable, optimizer: Optimizer,
         return make_shard_map_round(loss_fn, optimizer, cfg, mesh,
                                     client_axis=CLIENT_AXIS,
                                     topology=topology, pipeline=pipeline)
+    _refuse_mosaic_under_model_axis(cfg, pipeline)
     block = Cp // dc
     rules = mesh2d_rules() if rules is None else dict(rules)
     # unroll=True: RNG inside a while loop inside a partial-manual shard_map

@@ -1,8 +1,9 @@
 """Mesh-aware engine placement: the ``engine="auto"`` decision table.
 
 Pure arithmetic over (client count, device count, per-replica footprint,
-per-device memory budget) — no jax import, so the table is unit-pinnable
-without devices. ``repro.api.engines.resolve_engine`` consults
+per-device memory budget) — jax is imported only to read the device's own
+memory limit, so the table is unit-pinnable without devices.
+``repro.api.engines.resolve_engine`` consults
 :func:`choose_engine` with the process device count; launchers and dryrun
 feed the footprint from :func:`repro.configs.shapes.replica_footprint_bytes`
 (the spec carries it as the ``replica_bytes`` hint).
@@ -16,22 +17,24 @@ The rule, in order:
 3. multiple devices and a client axis worth sharding -> ``shard_map``;
 4. otherwise ``vmap``.
 
-The per-device budget defaults to a v5e chip (16 GiB, matching
-``repro.launch.dryrun.HBM_PER_CHIP``) and is overridable via the
-``REPRO_DEVICE_MEM_BYTES`` env var so CPU-simulated meshes can rehearse
-"does not fit" placements with byte-for-byte the production logic.
+The per-device budget is the ``bytes_limit`` that the first local device
+reports through ``memory_stats()``. A backend that reports none (the CPU)
+is an error, not a guess: the ``REPRO_DEVICE_MEM_BYTES`` env var (or an
+explicit ``hbm_bytes``) then supplies it, which is also how CPU-simulated
+meshes rehearse "does not fit" placements with byte-for-byte the
+production logic.
 """
 from __future__ import annotations
 
 import math
 import os
 
-DEFAULT_DEVICE_MEM_BYTES = 16 * 1024 ** 3   # v5e HBM, as launch.dryrun
 ENV_DEVICE_MEM = "REPRO_DEVICE_MEM_BYTES"
 
 
 def device_memory_budget(default: int | None = None) -> int:
-    """Per-device memory budget in bytes (env override > default > v5e)."""
+    """Per-device memory budget in bytes: the env override, else
+    ``default``, else the device's reported ``bytes_limit``."""
     env = os.environ.get(ENV_DEVICE_MEM)
     if env:
         budget = int(env)
@@ -39,7 +42,16 @@ def device_memory_budget(default: int | None = None) -> int:
             raise ValueError(f"{ENV_DEVICE_MEM} must be positive, "
                              f"got {budget}")
         return budget
-    return DEFAULT_DEVICE_MEM_BYTES if default is None else int(default)
+    if default is not None:
+        return int(default)
+    import jax
+    device = jax.devices()[0]
+    limit = (device.memory_stats() or {}).get("bytes_limit")
+    if not limit:
+        raise RuntimeError(f"{device.platform} device {device.device_kind!r} "
+                           f"reports no memory bytes_limit; set "
+                           f"{ENV_DEVICE_MEM}")
+    return int(limit)
 
 
 def replica_fits(replica_bytes: int, hbm_bytes: int | None = None) -> bool:

@@ -41,7 +41,8 @@ def _batch(seed=0):
 
 # ---------------------------- registry --------------------------------------
 
-def test_registry_contents():
+def test_registry_contents(monkeypatch):
+    monkeypatch.delenv(dispatch.KERNEL_DISABLE_ENV, raising=False)
     assert set(kernel_names()) == {"dp_clip_noise", "flash_attention",
                                    "rwkv6_scan", "mamba2_ssd",
                                    "quantize_decompress",
@@ -49,10 +50,9 @@ def test_registry_contents():
     with pytest.raises(KeyError):
         get_kernel("nope")
     for name in kernel_names():
-        # ref is the guaranteed floor; listing is ordered best-first
-        avail = available_backends(name)
-        assert avail[-1] == "ref"
-        assert set(avail) <= {"pallas", "interpret", "ref"}
+        # off the TPU every kernel runs interpreted and as its oracle;
+        # the listing is ordered best-first
+        assert available_backends(name) == ("interpret", "ref")
 
 
 def test_register_kernel_roundtrip():
@@ -93,9 +93,9 @@ def test_resolve_backend_env_override(monkeypatch):
 
 
 def test_resolve_backend_probe_fallback(monkeypatch):
-    """On TPU, auto walks pallas > interpret > ref by (monkeypatched)
-    capability — interpret is a sensible fallback there (same Mosaic
-    lowering semantics, and the oracle may not be tuned for the platform)."""
+    """On TPU, auto resolves to pallas or raises: a failed pallas probe is
+    an error even when interpret (or the oracle) would run — the chip
+    never silently runs a kernel off the Mosaic path."""
     monkeypatch.delenv(dispatch.KERNEL_BACKEND_ENV, raising=False)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
@@ -105,12 +105,17 @@ def test_resolve_backend_probe_fallback(monkeypatch):
     monkeypatch.setattr(dispatch, "backend_works",
                         works({"pallas": True, "interpret": True}))
     assert resolve_backend("dp_clip_noise", "auto") == "pallas"
-    monkeypatch.setattr(dispatch, "backend_works",
-                        works({"pallas": False, "interpret": True}))
-    assert resolve_backend("dp_clip_noise", "auto") == "interpret"
-    monkeypatch.setattr(dispatch, "backend_works",
-                        works({"pallas": False, "interpret": False}))
-    assert resolve_backend("dp_clip_noise", "auto") == "ref"
+    for table in ({"pallas": False, "interpret": True},
+                  {"pallas": False, "interpret": False}):
+        monkeypatch.setattr(dispatch, "backend_works", works(table))
+        monkeypatch.setattr(dispatch, "probe_error",
+                            lambda name, backend: ValueError("refused"))
+        with pytest.raises(RuntimeError, match="pallas probe") as info:
+            resolve_backend("dp_clip_noise", "auto")
+        assert isinstance(info.value.__cause__, ValueError)
+    # explicit backends still win on TPU (tests and tooling)
+    assert resolve_backend("dp_clip_noise", "interpret") == "interpret"
+    assert resolve_backend("dp_clip_noise", "ref") == "ref"
 
 
 def test_resolve_backend_ref_outranks_interpret_off_tpu(monkeypatch):
@@ -134,22 +139,31 @@ def test_resolve_backend_ref_outranks_interpret_off_tpu(monkeypatch):
 
 
 def test_backend_works_probe_failure_reads_as_unavailable(monkeypatch):
-    """A drifted-API exception inside the probe means False, not a raise."""
+    """A probe that raises reads as unavailable in backend_works, keeps
+    its exception in probe_error, and — on a TPU — makes auto raise with
+    that exception attached instead of falling back."""
     entry = dispatch._entry("dp_clip_noise")
+    drift = AttributeError("simulated pallas API drift")
 
     def boom(_impl):
-        raise AttributeError("simulated pallas API drift")
+        raise drift
 
     monkeypatch.setitem(dispatch._REGISTRY, "dp_clip_noise",
                         dispatch.KernelEntry(name=entry.name,
                                              pallas_fn=entry.pallas_fn,
                                              ref_fn=entry.ref_fn,
                                              probe=boom))
+    monkeypatch.delenv(dispatch.KERNEL_BACKEND_ENV, raising=False)
     dispatch.backend_works.cache_clear()
     try:
         assert backend_works("dp_clip_noise", "interpret") is False
+        assert dispatch.probe_error("dp_clip_noise", "interpret") is drift
         assert backend_works("dp_clip_noise", "ref") is True
-        assert resolve_backend("dp_clip_noise", "auto") == "ref"
+        assert resolve_backend("dp_clip_noise", "auto") == "ref"   # off TPU
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(RuntimeError) as info:
+            resolve_backend("dp_clip_noise", "auto")
+        assert info.value.__cause__ is drift
     finally:
         dispatch.backend_works.cache_clear()
 
@@ -171,6 +185,10 @@ def test_disable_env_simulates_oracle_only(monkeypatch):
         assert available_backends("dp_clip_noise") == ("ref",)
         assert resolve_backend("dp_clip_noise", "auto") == "ref"
         assert backend_works("dp_clip_noise", "ref") is True  # not disableable
+        # on a TPU a disabled pallas is an error, never a demotion to ref
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(RuntimeError, match="disabled"):
+            resolve_backend("dp_clip_noise", "auto")
     finally:
         dispatch.backend_works.cache_clear()
 
@@ -223,8 +241,6 @@ def test_engine_auto_with_kernel_backend_auto(monkeypatch):
 def test_engine_round_parity_ref_vs_interpret(engine):
     """vmap/map/shard_map rounds are numerically identical (atol 1e-5)
     whether the clip+noise hot path runs on "ref" or "interpret"."""
-    if "interpret" not in available_backends("dp_clip_noise"):
-        pytest.skip("pallas interpret unavailable on this jax")
     params0 = init_linear(DIM)
     batch = _batch()
 

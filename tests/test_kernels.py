@@ -1,12 +1,11 @@
-"""Backend-parity harness: every registered kernel × every available backend
+"""Backend-parity harness: every registered kernel × the interpret backend
 × a shape/dtype sweep, checked against the ref.py pure-jnp oracles.
 
-The parametrization is driven by the dispatch registry itself
-(``kernel_names()`` × ``available_backends(name)``), so on a jax whose
-Pallas API has drifted the probes exclude "interpret"/"pallas" and the
-suite still runs (and passes) on the "ref" oracle — green degradation
-instead of collection errors. Property tests run under real hypothesis or
-the ``_hypothesis_compat`` replay shim (installed by conftest).
+The backends are fixed, not probed at collection: a kernel whose Pallas
+body stops running in interpret mode fails here instead of dropping out
+of the sweep. Property tests run on both CPU backends (interpret, ref),
+under real hypothesis or the ``_hypothesis_compat`` replay shim
+(installed by conftest).
 """
 import jax
 import jax.numpy as jnp
@@ -22,6 +21,10 @@ from repro.kernels.dispatch import (
     kernel_names,
 )
 from repro.kernels.ops import dp_clip_noise_tree
+
+# the backends every CPU run must have; ref IS the oracle, so the parity
+# sweep compares interpret against it
+CPU_BACKENDS = ("interpret", "ref")
 
 
 def _assert_trees_close(got, want, tol):
@@ -115,7 +118,9 @@ def _mamba_case(s, chunk, dtype):
     return build
 
 
-def _cohort_case(s, k, d, dtype, scatter=False):
+def _cohort_case(s, k, d, dtype, scatter=False, block_rows=None):
+    tuning = {} if block_rows is None else {"block_rows": block_rows}
+
     def build():
         ks = jax.random.split(jax.random.PRNGKey(5), 3)
         cache = jax.random.normal(ks[0], (s, d), dtype)
@@ -123,8 +128,8 @@ def _cohort_case(s, k, d, dtype, scatter=False):
         if scatter:
             rows = jax.random.normal(ks[2], (k, d), dtype)
             # pure row copy: exact on every backend, no tolerance
-            return (cache, slots, rows), {}, 0.0
-        return (cache, slots), {}, 0.0
+            return (cache, slots, rows), tuning, 0.0
+        return (cache, slots), tuning, 0.0
     return build
 
 
@@ -137,6 +142,9 @@ CASES = {
         for s in (100.0, 1e-3)
     ] + [
         ("clip-only-n1000", _dp_clip_only_case(1000)),
+        # 40 rows in 32-row blocks: no padding, the last block overhangs
+        ("overhang-n5120", _dp_case(40 * 128, jnp.float32, 100.0)),
+        ("clip-only-overhang-n5120", _dp_clip_only_case(40 * 128)),
     ],
     "quantize_decompress": [
         (f"n{n}-b{bits}-{np.dtype(d).name if d != jnp.bfloat16 else 'bf16'}",
@@ -147,6 +155,7 @@ CASES = {
             (64 * 1024 + 3, 8, jnp.float32, 1e-3),
             (1024, 8, jnp.bfloat16, 1.0),
             (255, 1, jnp.float32, 1.0),
+            (40 * 128, 8, jnp.float32, 1.0),       # last block overhangs
         ]
     ],
     "flash_attention": [
@@ -180,30 +189,28 @@ CASES = {
         ("gather-s16-d33-bf16", _cohort_case(16, 4, 33, jnp.bfloat16)),
         ("scatter-s16-d33-bf16", _cohort_case(16, 4, 33, jnp.bfloat16,
                                               scatter=True)),
+        # several D blocks, the last one partial, plus a ragged lane tail
+        ("gather-s8-d5123-br16", _cohort_case(8, 3, 40 * 128 + 3,
+                                              jnp.float32, block_rows=16)),
+        ("scatter-s8-d5120-br16", _cohort_case(8, 3, 40 * 128, jnp.float32,
+                                               scatter=True, block_rows=16)),
     ],
 }
 
 
 def _parity_params():
+    for name in sorted(CASES):
+        for case_id, build in CASES[name]:
+            yield pytest.param(name, "interpret", build,
+                               id=f"{name}-interpret-{case_id}")
+
+
+def test_case_sweep_covers_registry():
     assert set(CASES) == set(kernel_names()), \
         "case sweep drifted from the dispatch registry"
-    for name in kernel_names():
-        for backend in available_backends(name):
-            if backend == "ref":
-                continue   # ref IS the oracle; ref-vs-ref proves nothing
-            for case_id, build in CASES[name]:
-                yield pytest.param(name, backend, build,
-                                   id=f"{name}-{backend}-{case_id}")
 
 
-@pytest.mark.parametrize(
-    "name,backend,build",
-    list(_parity_params())
-    # oracle-only env (all pallas probes failed / disabled): nothing to
-    # compare — parametrize an explicit skip instead of an empty set
-    or [pytest.param(None, None, None,
-                     id="oracle-only-env",
-                     marks=pytest.mark.skip("no non-ref backend available"))])
+@pytest.mark.parametrize("name,backend,build", list(_parity_params()))
 def test_kernel_backend_parity(name, backend, build):
     args, kwargs, tol = build()
     got = get_kernel(name, backend)(*args, **kwargs)
@@ -218,6 +225,14 @@ def test_every_kernel_has_ref_backend():
         assert "ref" in available_backends(name)
 
 
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_every_kernel_probe_passes_in_interpret_mode(name):
+    """Each registered probe (the TPU resolution check) passes on the
+    interpret backend, so a failure on the chip is the compiler's."""
+    from repro.kernels.dispatch import probe_error
+    assert probe_error(name, "interpret") is None
+
+
 # ------------------------- dp_clip_noise properties ------------------------
 
 @settings(max_examples=25, deadline=None)
@@ -228,9 +243,9 @@ def test_dp_clip_noise_norm_bound(n, clip, seed):
     bound), on every available backend."""
     g = jax.random.normal(jax.random.PRNGKey(seed), (n,), jnp.float32) * 10.0
     noise = jnp.zeros((n,), jnp.float32)
-    for backend in available_backends("dp_clip_noise"):
+    for backend in CPU_BACKENDS:
         got, norm = get_kernel("dp_clip_noise", backend)(
-            g, noise, clip, 0.0, block=1024)
+            g, noise, clip, 0.0, block=4096)
         out_norm = float(jnp.linalg.norm(got.astype(jnp.float32)))
         assert out_norm <= min(clip, float(norm)) * (1 + 1e-4)
 
@@ -242,9 +257,9 @@ def test_dp_clip_noise_passthrough_below_clip(n, seed):
     g = jax.random.normal(jax.random.PRNGKey(seed), (n,), jnp.float32)
     g = g / jnp.maximum(jnp.linalg.norm(g), 1e-12) * 0.5   # norm 0.5 < 1
     noise = jnp.zeros((n,), jnp.float32)
-    for backend in available_backends("dp_clip_noise"):
+    for backend in CPU_BACKENDS:
         got, norm = get_kernel("dp_clip_noise", backend)(
-            g, noise, 1.0, 0.0, block=1024)
+            g, noise, 1.0, 0.0, block=4096)
         np.testing.assert_allclose(np.asarray(got), np.asarray(g),
                                    rtol=1e-6, atol=1e-7)
         assert float(norm) <= 0.5 * (1 + 1e-5)
@@ -255,12 +270,33 @@ def test_dp_clip_noise_tree_dtype_preservation():
     tree = {"w": jax.random.normal(jax.random.PRNGKey(0), (9, 4),
                                    jnp.bfloat16) * 10,
             "b": jax.random.normal(jax.random.PRNGKey(1), (7,), jnp.float32)}
-    for backend in available_backends("dp_clip_noise"):
+    for backend in CPU_BACKENDS:
         out, norm = dp_clip_noise_tree(tree, jax.random.PRNGKey(2), 1.0, 0.3,
                                        backend=backend)
         assert jax.tree.map(lambda x: x.dtype, out) == \
             jax.tree.map(lambda x: x.dtype, tree)
         assert float(norm) > 0
+
+
+@pytest.mark.parametrize("name", ["dp_clip_noise", "quantize_decompress"])
+def test_overhang_mask_under_client_vmap(name):
+    """The engines vmap the kernels over clients, which adds a grid axis:
+    the last block's overhang (NaN-filled in interpret mode) must still be
+    masked by the block's step along the vector, not by the client."""
+    ks = jax.random.split(jax.random.PRNGKey(4), 2)
+    x = jax.random.normal(ks[0], (3, 40 * 128), jnp.float32) * 50
+    y = jax.random.uniform(ks[1], (3, 40 * 128), jnp.float32)
+    extra = ((1.0, jnp.asarray([0.1, 0.2, 0.3])) if name == "dp_clip_noise"
+             else (8,))
+
+    def run(backend):
+        kernel = get_kernel(name, backend)
+        if name == "dp_clip_noise":
+            return jax.vmap(lambda a, b, s: kernel(a, b, extra[0], s,
+                                                   block=4096))(x, y, extra[1])
+        return jax.vmap(lambda a, b: kernel(a, b, extra[0], block=4096))(x, y)
+
+    _assert_trees_close(run("interpret"), run("ref"), 1e-5)
 
 
 def test_dp_clip_noise_tree_matches_core():
@@ -269,7 +305,7 @@ def test_dp_clip_noise_tree_matches_core():
             "b": {"c": jax.random.normal(jax.random.PRNGKey(1), (11,))}}
     key = jax.random.PRNGKey(2)
     want, wnorm = clip_tree(tree, 1.0)
-    for backend in available_backends("dp_clip_noise"):
+    for backend in CPU_BACKENDS:
         got, norm = dp_clip_noise_tree(tree, key, 1.0, 0.0, backend=backend)
         _assert_trees_close(got, want, 1e-5)
         np.testing.assert_allclose(float(norm), float(wnorm), rtol=1e-5)

@@ -152,3 +152,40 @@ def test_apply_execs_with_guard_and_profile_env(monkeypatch):
     assert "--xla_force_host_platform_device_count=2" in \
         captured["env"]["XLA_FLAGS"]
     assert captured["env"]["TF_CPP_MIN_LOG_LEVEL"] == "4"
+
+
+# ---------------------------------------------------------------------------
+# enable_compile_cache: JAX_COMPILATION_CACHE_DIR wins, else a fixed path
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("env_dir", [None, "/elsewhere/jax-cache"],
+                         ids=["default", "env"])
+def test_enable_compile_cache(monkeypatch, env_dir):
+    from pathlib import Path
+
+    import jax
+
+    from repro.launch.env import (
+        COMPILE_CACHE_ENV,
+        DEFAULT_COMPILE_CACHE,
+        enable_compile_cache,
+    )
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv(COMPILE_CACHE_ENV, raising=False)
+    else:
+        monkeypatch.setenv(COMPILE_CACHE_ENV, env_dir)
+    try:
+        got = enable_compile_cache()
+        if env_dir is None:
+            # a fixed directory inside the checkout, which git ignores
+            root = Path(__file__).resolve().parents[1]
+            assert got == str(DEFAULT_COMPILE_CACHE) == str(root / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+            assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+        else:
+            # JAX reads the variable itself; no other cache is set in code
+            assert got == env_dir
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

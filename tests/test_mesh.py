@@ -4,7 +4,8 @@ Three layers of coverage:
 
 * placement (pure python): the engine="auto" decision table and default
   mesh-shape arithmetic of :mod:`repro.mesh.placement`, pinned value by
-  value, plus the ``REPRO_DEVICE_MEM_BYTES`` override.
+  value, plus the device-reported budget and the ``REPRO_DEVICE_MEM_BYTES``
+  override.
 * spec plumbing: FederationSpec validation of ``mesh_shape`` /
   ``sharding_rules`` / ``replica_bytes``, engine_key cache inclusion, and
   logical-axis rule resolution (mesh2d_rules dedupe).
@@ -23,7 +24,6 @@ import pytest
 
 from repro.api import FederationSpec, init_state, resolve_engine, run_round
 from repro.mesh.placement import (
-    DEFAULT_DEVICE_MEM_BYTES,
     ENV_DEVICE_MEM,
     choose_engine,
     default_mesh_shape,
@@ -75,10 +75,29 @@ def _run(spec, batch, dim=DIM, rounds=2):
 GIB = 1024 ** 3
 
 
+class _FakeDevice:
+    """A device that reports ``memory_stats`` as a TPU does (or not)."""
+    platform, device_kind = "tpu", "TPU v5 lite"
+
+    def __init__(self, stats):
+        self._stats = stats
+
+    def memory_stats(self):
+        return self._stats
+
+
 def test_device_memory_budget_default_and_env(monkeypatch):
     monkeypatch.delenv(ENV_DEVICE_MEM, raising=False)
-    assert device_memory_budget() == DEFAULT_DEVICE_MEM_BYTES == 16 * GIB
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: [_FakeDevice({"bytes_limit": 15 * GIB})])
+    assert device_memory_budget() == 15 * GIB     # the device's own limit
     assert device_memory_budget(default=7) == 7
+    # a backend that reports no limit is an error, not a 16 GiB guess
+    for stats in (None, {}):
+        monkeypatch.setattr(jax, "devices", lambda *a, s=stats: [
+            _FakeDevice(s)])
+        with pytest.raises(RuntimeError, match=ENV_DEVICE_MEM):
+            device_memory_budget()
     monkeypatch.setenv(ENV_DEVICE_MEM, str(2 * GIB))
     assert device_memory_budget() == 2 * GIB
     assert device_memory_budget(default=7) == 2 * GIB   # env wins
@@ -87,10 +106,14 @@ def test_device_memory_budget_default_and_env(monkeypatch):
         device_memory_budget()
 
 
-def test_replica_fits():
+def test_replica_fits(monkeypatch):
     assert replica_fits(GIB, hbm_bytes=2 * GIB)
     assert not replica_fits(3 * GIB, hbm_bytes=2 * GIB)
-    assert replica_fits(DEFAULT_DEVICE_MEM_BYTES)       # default budget
+    monkeypatch.delenv(ENV_DEVICE_MEM, raising=False)
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: [_FakeDevice({"bytes_limit": 4 * GIB})])
+    assert replica_fits(4 * GIB)                        # device budget
+    assert not replica_fits(4 * GIB + 1)
 
 
 def test_n_client_shards_divisor_table():
@@ -199,6 +222,27 @@ def test_mesh2d_rules_resolve_dedupes_repeated_axis():
             None, "model")
     # outside any rules context resolution is the identity placement
     assert resolve_spec(("fsdp", "tp")) == jax.sharding.PartitionSpec()
+
+
+@pytest.mark.parametrize("backend", ["pallas", "ref"])
+def test_mesh_2d_refuses_mosaic_kernels_under_model_axis(backend):
+    """A Mosaic kernel cannot be partitioned over the auto model axis: the
+    model-sharded round refuses it at build time, not at chip compile."""
+    from repro.core.aggregation import QSGD
+    from repro.core.fl import FLConfig
+    from repro.mesh.engine import _refuse_mosaic_under_model_axis
+
+    class _Pipe:
+        compressor = QSGD(8, backend)
+
+    for cfg, pipe in ((FLConfig(n_clients=2, tau=1, kernel_backend=backend),
+                       None),
+                      (FLConfig(n_clients=2, tau=1), _Pipe())):
+        if backend == "pallas":
+            with pytest.raises(ValueError, match="model axis"):
+                _refuse_mosaic_under_model_axis(cfg, pipe)
+        else:
+            _refuse_mosaic_under_model_axis(cfg, pipe)
 
 
 def test_resolve_engine_single_device_never_mesh(monkeypatch):
