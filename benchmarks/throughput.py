@@ -40,18 +40,6 @@ resident row's ``host_syncs_per_round == 0`` and its rounds/s against the
 chunk-boundary baseline (noise margin; the committed full-grid JSON shows
 it strictly ahead).
 
-A **kernel roofline** section projects the measured kernels onto the TPU
-v5e roofline of :mod:`repro.utils.roofline`: for the qsgd
-``quantize_decompress`` kernel and the PR-8 ``cohort_gather_scatter``
-kernel, each probe-available backend is timed on a fixed shape and the
-row reports analytic FLOPs/bytes, achieved GFLOP/s and GB/s on this
-host, the v5e roofline bound (t_compute vs t_memory, bottleneck term),
-and the headroom factor ``wall / v5e_bound`` — how far this backend on
-this host sits above what the target part's roofline admits. Both
-kernels are streaming (O(1) flops/byte), so ``--check`` gates that every
-row's projected bottleneck is the memory term — a compute-bound verdict
-means the analytic model (or the kernel) regressed.
-
 A **mesh-plane** section (needs >= 8 devices; rows are skipped with a
 reason otherwise, so the committed full-grid JSON must come from an
 ``--xla_force_host_platform_device_count=8`` run) times the 2D client x
@@ -102,7 +90,6 @@ from repro.asyncfl import (
     sync_round_duration,
     train_async,
 )
-from repro.kernels.dispatch import backend_works, get_kernel
 from repro.models.linear import init_linear, logreg_loss
 from repro.optim import sgd
 from repro.population import (
@@ -113,7 +100,6 @@ from repro.population import (
     synthetic_population,
     train_population,
 )
-from repro.utils.roofline import HBM_BW, RooflineTerms
 
 # fixed CPU reference federation: small enough that driver overhead (the
 # thing this benchmark tracks) dominates — per-round host cost is fixed
@@ -318,99 +304,6 @@ def run_resident_cohort(smoke: bool) -> dict:
             "speedup_resident_vs_chunk": round(speedup, 2)}
 
 
-# analytic per-call roofline terms for the streamed kernels. Coarse HLO-level
-# op counts, deliberately simple: quantize_decompress reads x and u and
-# writes the dequantized y (3 f32 arrays, 12N bytes) and spends ~6 flops per
-# element (abs-max reduction, normalize, scale, jitter-add, floor, dequant);
-# cohort gather is a pure row copy — K*D read + K*D write, zero flops — so
-# its roofline position is memory-bound by construction; dp_clip_noise (the
-# per-round DP hot path: square+reduce for the norm, clip scale, fused
-# noise multiply-add, ~5 flops/element) reads g and noise and writes y —
-# 12N bytes, streaming like the others.
-def _kernel_scenarios(smoke: bool) -> list[dict]:
-    n = 1 << 16 if smoke else 1 << 20
-    s_rows, d = (128, 256) if smoke else (512, 4096)
-    key = jax.random.PRNGKey(0)
-    kx, ku, kc, kn = jax.random.split(key, 4)
-    x = jax.random.normal(kx, (n,), jnp.float32)
-    u = jax.random.uniform(ku, (n,), jnp.float32)
-    noise = jax.random.normal(kn, (n,), jnp.float32)
-    cachemat = jax.random.normal(kc, (s_rows, d), jnp.float32)
-    slots = jnp.asarray(np.arange(0, s_rows, s_rows // C)[:C], jnp.int32)
-    return [
-        {"kernel": "quantize_decompress",
-         "shape": f"N={n}", "args": (x, u),
-         "call": lambda impl: (lambda x_, u_: impl(x_, u_, 4)),
-         "flops": 6.0 * n, "hbm_bytes": 12.0 * n},
-        {"kernel": "cohort_gather_scatter",
-         "shape": f"S={s_rows} K={C} D={d}", "args": (cachemat, slots),
-         "call": lambda impl: (lambda c_, s_: impl(c_, s_)),
-         "flops": 0.0, "hbm_bytes": 2.0 * C * d * 4.0},
-        {"kernel": "dp_clip_noise",
-         "shape": f"N={n}", "args": (x, noise),
-         "call": lambda impl: (lambda g_, n_: impl(g_, n_, 1.0, 0.5)),
-         "flops": 5.0 * n, "hbm_bytes": 12.0 * n},
-    ]
-
-
-def run_kernel_roofline(smoke: bool) -> dict:
-    """Achieved-vs-peak per kernel backend, projected on the v5e roofline.
-
-    Each probe-available backend is timed (best-of-repeats, many calls per
-    timing to amortize dispatch) on a fixed shape; the row pairs the
-    achieved GFLOP/s / GB/s on THIS host with the v5e roofline bound for
-    the same analytic FLOPs/bytes. ``headroom_vs_v5e`` = measured wall /
-    roofline bound: how many times slower this backend+host runs than the
-    target part's roofline admits (1.0 would be a roofline-saturating
-    kernel on real hardware).
-    """
-    iters, repeats = (5, 2) if smoke else (20, 3)
-    rows = []
-    for sc in _kernel_scenarios(smoke):
-        for backend in ("pallas", "interpret", "ref"):
-            if not backend_works(sc["kernel"], backend):
-                continue
-            if backend == "interpret" and not smoke:
-                # interpret mode executes the kernel body block-by-block as
-                # jax ops (~100x the oracle on CPU); time it at the smoke
-                # shape only so the full grid stays minutes, not hours
-                continue
-            fn = jax.jit(sc["call"](get_kernel(sc["kernel"], backend)))
-            out = fn(*sc["args"])
-            jax.block_until_ready(out)          # compile warm-up
-            best = float("inf")
-            for _ in range(repeats):
-                t0 = time.perf_counter()
-                for _ in range(iters):
-                    out = fn(*sc["args"])
-                jax.block_until_ready(out)
-                best = min(best, (time.perf_counter() - t0) / iters)
-            terms = RooflineTerms(flops=sc["flops"],
-                                  hbm_bytes=sc["hbm_bytes"], coll_bytes=0.0)
-            bound = max(terms.t_compute, terms.t_memory)
-            row = {
-                "kernel": sc["kernel"], "backend": backend,
-                "shape": sc["shape"],
-                "flops": sc["flops"], "hbm_bytes": sc["hbm_bytes"],
-                "wall_us": round(best * 1e6, 2),
-                "achieved_gflop_s": round(sc["flops"] / best / 1e9, 2),
-                "achieved_gb_s": round(sc["hbm_bytes"] / best / 1e9, 2),
-                "fraction_of_v5e_hbm_bw": round(
-                    sc["hbm_bytes"] / best / HBM_BW, 6),
-                "v5e_bound_us": round(bound * 1e6, 4),
-                "v5e_bottleneck": ("compute" if terms.t_compute
-                                   > terms.t_memory else "memory"),
-                "headroom_vs_v5e": round(best / bound, 1),
-                "v5e_roofline": terms.as_dict(),
-            }
-            rows.append(row)
-            print(f"roofline {sc['kernel']:22s} {backend:9s} {sc['shape']:18s}"
-                  f" {row['wall_us']:>10.1f} us  {row['achieved_gb_s']:>8.2f}"
-                  f" GB/s ({row['headroom_vs_v5e']}x off v5e "
-                  f"{row['v5e_bottleneck']} roof)")
-    return {"iters": iters, "repeats": repeats, "rows": rows}
-
-
 def run_mesh_plane(smoke: bool) -> dict:
     """2D mesh engine vs the 1D shard_map plane, plus the too-big-model
     placement gate. See the module docstring for what each row means."""
@@ -572,7 +465,6 @@ def run_grid(smoke: bool) -> dict:
         "speedup_fused_vs_per_round": speedups,
         "cohort_scaling": cohort_rows,
         "resident_cohort": run_resident_cohort(smoke),
-        "kernel_roofline": run_kernel_roofline(smoke),
         "async_hetero": run_async_hetero(smoke),
         "mesh_plane": run_mesh_plane(smoke),
     }
@@ -640,21 +532,6 @@ def main(argv=None) -> int:
             print(f"REGRESSION: resident driver slower than the "
                   f"chunk-boundary path: {rc}")
             return 1
-        # kernel roofline: all three streamed kernels must be covered and
-        # every row must project memory-bound on v5e — these kernels do
-        # O(1) flops per byte, so a compute-bound verdict means the
-        # analytic model (or the kernel itself) regressed
-        kr = report["kernel_roofline"]["rows"]
-        covered = {r["kernel"] for r in kr}
-        if not {"quantize_decompress", "cohort_gather_scatter",
-                "dp_clip_noise"} <= covered:
-            print(f"REGRESSION: kernel roofline rows missing: {covered}")
-            return 1
-        off_roof = [r for r in kr if r["v5e_bottleneck"] != "memory"]
-        if off_roof:
-            print(f"REGRESSION: streamed kernel projects compute-bound: "
-                  f"{off_roof}")
-            return 1
         # mesh plane (only when the device count admitted it): the
         # degenerate (8,1) mesh runs the shard_map protocol through the
         # mesh engine — large noise margin (0.5) because the padding
@@ -692,7 +569,6 @@ def main(argv=None) -> int:
               f"({[r['population'] for r in rows]}); "
               f"resident cohort 0 syncs/round at "
               f"{rc['speedup_resident_vs_chunk']}x chunk-boundary; "
-              f"roofline memory-bound for {sorted(covered)}; "
               f"async {ah['sim_speedup']}x sync in simulated seconds; "
               + ("mesh plane skipped (device count)" if mp.get("skipped")
                  else "mesh plane placed + within margin"))

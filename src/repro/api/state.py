@@ -16,6 +16,19 @@ state down two paths (what-if probing), copy the donated leaves first
 (``state.replace(params=jax.tree.map(jnp.copy, state.params), ...)``) or
 rebuild via ``init_state``. Host-side data (checkpoints on disk, the rho
 snapshot, np views taken earlier) is never affected.
+
+PROFILER SPANS: the drivers mark their host work with
+``jax.profiler.TraceAnnotation`` spans, which land in a ``jax.profiler``
+trace beside the device's ops and cost about a microsecond each when no
+profiler runs:
+
+    dppasgd.round      one step of the driver loop (``round=`` the first
+                       round it completes; ``rounds=`` under the chunked
+                       driver): batch build, dispatch, ledger, host reads
+    dppasgd.batch      building a round's (or a chunk's) host batch
+    dppasgd.ledger     the host zCDP arithmetic: budget probes, charges
+    dppasgd.host_read  one blocking device->host read (a metric value, a
+                       participation mask)
 """
 from __future__ import annotations
 
@@ -26,6 +39,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.api.engines import chunked_round_fn_for, round_fn_for
 from repro.api.spec import FederationSpec
@@ -169,7 +183,8 @@ def accountant_view(spec: FederationSpec,
 
 
 def max_epsilon(spec: FederationSpec, state: FLState) -> float:
-    return accountant_view(spec, state).max_epsilon()
+    with TraceAnnotation("dppasgd.ledger"):
+        return accountant_view(spec, state).max_epsilon()
 
 
 def peek_epsilon_fast(spec: FederationSpec, state: FLState,
@@ -192,11 +207,12 @@ def exceeds_budgets(spec: FederationSpec, state: FLState) -> str | None:
     ``PrivacyAccountant.peek_epsilon``), conservatively assuming the worst
     client participates next round (its per-step rho still carries the
     subsampling amplification factor)."""
-    if state.resource_spent + spec.round_cost() > spec.c_th:
-        return "resource"
-    if peek_epsilon_fast(spec, state, spec.tau) > spec.eps_th:
-        return "privacy"
-    return None
+    with TraceAnnotation("dppasgd.ledger"):
+        if state.resource_spent + spec.round_cost() > spec.c_th:
+            return "resource"
+        if peek_epsilon_fast(spec, state, spec.tau) > spec.eps_th:
+            return "privacy"
+        return None
 
 
 def rounds_within_budgets(spec: FederationSpec, state: FLState,
@@ -212,20 +228,22 @@ def rounds_within_budgets(spec: FederationSpec, state: FLState,
     projection, so a chunk sized by this bound never contains a round the
     per-round driver would have refused (it may end early; the training
     loop re-probes on the realized ledger and continues)."""
-    charges = round_rho_charges(spec)
-    rho = state.rho
-    spent = state.resource_spent
-    cost = spec.round_cost()
-    n = 0
-    while n < limit:
-        if spent + cost > spec.c_th:
-            return n, "resource"
-        if zcdp_to_dp(float(np.max(rho + charges)), spec.delta) > spec.eps_th:
-            return n, "privacy"
-        rho = rho + charges
-        spent = spent + cost
-        n += 1
-    return n, None
+    with TraceAnnotation("dppasgd.ledger"):
+        charges = round_rho_charges(spec)
+        rho = state.rho
+        spent = state.resource_spent
+        cost = spec.round_cost()
+        n = 0
+        while n < limit:
+            if spent + cost > spec.c_th:
+                return n, "resource"
+            if zcdp_to_dp(float(np.max(rho + charges)),
+                          spec.delta) > spec.eps_th:
+                return n, "privacy"
+            rho = rho + charges
+            spent = spent + cost
+            n += 1
+        return n, None
 
 
 def _raise_budget(which: str, spec: FederationSpec):
@@ -257,7 +275,6 @@ def run_round(spec: FederationSpec, state: FLState, batch: Any,
             _raise_budget(which, spec)
     key, sub = jax.random.split(state.key)
     sig = sigmas_for(spec)
-    per_round = round_rho_charges(spec)
     residual = state.residual
     if spec.has_pipeline():
         # pipeline round: sample this round's participant set from the
@@ -266,17 +283,25 @@ def run_round(spec: FederationSpec, state: FLState, batch: Any,
         sub, mask_key = jax.random.split(sub)
         mask = participation_mask(mask_key, spec.n_clients,
                                   spec.participants_per_round())
-        mask_np = np.asarray(mask)
+        with TraceAnnotation("dppasgd.host_read"):
+            mask_np = np.asarray(mask)
         new_p, new_s, residual, ms = round_fn_for(spec)(
             state.params, state.opt_state, batch, sub, sig, mask,
             state.residual)
-        rho = state.rho + np.where(mask_np > 0, per_round, 0.0)
-        n_participants = int(mask_np.sum())
     else:
         new_p, new_s, ms = round_fn_for(spec)(state.params, state.opt_state,
                                               batch, sub, sig)
-        rho = state.rho + per_round
-        n_participants = spec.n_clients
+        mask_np = None
+    # the host ledger runs while the device computes the round
+    with TraceAnnotation("dppasgd.ledger"):
+        per_round = round_rho_charges(spec)
+        if mask_np is None:
+            rho = state.rho + per_round
+            n_participants = spec.n_clients
+        else:
+            rho = state.rho + np.where(mask_np > 0, per_round, 0.0)
+            n_participants = int(mask_np.sum())
+        round_epsilon = zcdp_to_dp(float(np.max(rho)), spec.delta)
     new_state = state.replace(
         params=new_p, opt_state=new_s, key=key, residual=residual, rho=rho,
         steps=state.steps + spec.tau,
@@ -285,7 +310,7 @@ def run_round(spec: FederationSpec, state: FLState, batch: Any,
     rec = dict(ms)                 # lazy: 0-d device arrays, no sync
     rec["round"] = new_state.rounds_done
     rec["iterations"] = new_state.rounds_done * spec.tau
-    rec["max_epsilon"] = zcdp_to_dp(float(np.max(rho)), spec.delta)
+    rec["max_epsilon"] = round_epsilon
     rec["resource_spent"] = new_state.resource_spent
     rec["participants"] = float(n_participants)
     return new_state, rec
@@ -359,7 +384,8 @@ def run_rounds(spec: FederationSpec, state: FLState, batches: Any,
             state.params, state.opt_state, batches, state.key, sig,
             state.residual)
         _prefetch()
-        masks_np = np.asarray(masks)       # THE one blocking sync per chunk
+        with TraceAnnotation("dppasgd.host_read"):
+            masks_np = np.asarray(masks)   # THE one blocking sync per chunk
         participants = masks_np.sum(axis=1)
     else:
         new_p, new_s, key, ms = fn(state.params, state.opt_state, batches,
@@ -370,10 +396,12 @@ def run_rounds(spec: FederationSpec, state: FLState, batches: Any,
         participants = np.full((n_rounds,), float(spec.n_clients))
     # exact ledger replay, hoisted to the chunk boundary: ONE accountant
     # materialization + one vectorized step_many over the realized masks
-    acc = accountant_view(spec, state)
-    worst_rho = acc.step_many([spec.tau] * n_rounds, masks=masks_np,
-                              q=spec.accounting_q())
-    rho = np.asarray([acc.rho(m) for m in range(spec.n_clients)], np.float64)
+    with TraceAnnotation("dppasgd.ledger"):
+        acc = accountant_view(spec, state)
+        worst_rho = acc.step_many([spec.tau] * n_rounds, masks=masks_np,
+                                  q=spec.accounting_q())
+        rho = np.asarray([acc.rho(m) for m in range(spec.n_clients)],
+                         np.float64)
     recs = []
     spent = state.resource_spent
     for r in range(n_rounds):
@@ -401,9 +429,15 @@ def materialize_record(rec: dict) -> dict:
     floats — the drivers' one deliberate sync point. ``run_round`` /
     ``run_rounds`` return records lazily (loss etc. stay 0-d device
     arrays) so recording a round never blocks the next dispatch; convert
-    at history-append or read time via this helper."""
-    return {k: (v if isinstance(v, (bool, int, float, str)) else float(v))
-            for k, v in rec.items()}
+    at history-append or read time via this helper. Each read of a device
+    value is one ``dppasgd.host_read`` span."""
+    def host(v):
+        if isinstance(v, jax.Array):
+            with TraceAnnotation("dppasgd.host_read"):
+                return float(v)
+        return v if isinstance(v, (bool, int, float, str)) else float(v)
+
+    return {k: host(v) for k, v in rec.items()}
 
 
 # ---------------------------------------------------------------------------
@@ -416,6 +450,11 @@ def round_batch(spec: FederationSpec, sampler: Callable, rng) -> Any:
     ``sampler(client, tau, rng)`` returns one client's pytree with leading
     axes (tau, B, ...).
     """
+    with TraceAnnotation("dppasgd.batch"):
+        return _stack_clients(spec, sampler, rng)
+
+
+def _stack_clients(spec: FederationSpec, sampler: Callable, rng) -> Any:
     per_client = [sampler(m, spec.tau, rng) for m in range(spec.n_clients)]
     return jax.tree.map(lambda *xs: np.stack(xs), *per_client)
 
@@ -427,8 +466,10 @@ def round_batches(spec: FederationSpec, sampler: Callable, rng,
     order ``n_rounds`` sequential :func:`round_batch` calls would (so a
     chunked driver consumes the sampler stream identically to the
     per-round one)."""
-    rounds = [round_batch(spec, sampler, rng) for _ in range(n_rounds)]
-    return jax.tree.map(lambda *xs: np.stack(xs), *rounds)
+    with TraceAnnotation("dppasgd.batch"):
+        rounds = [_stack_clients(spec, sampler, rng)
+                  for _ in range(n_rounds)]
+        return jax.tree.map(lambda *xs: np.stack(xs), *rounds)
 
 
 def collapse_clients(params: Any, topology: str) -> Any:
@@ -503,8 +544,10 @@ def budget_train_loop(*, state, max_rounds: int, eval_fn: Callable | None,
         while rounds_done(state) < max_rounds:
             if exceeds(state):
                 break
-            state, rec = run_single(state)
-            rec = materialize_record(rec)
+            with TraceAnnotation("dppasgd.round",
+                                 round=rounds_done(state) + 1):
+                state, rec = run_single(state)
+                rec = materialize_record(rec)
             history.append(rec)
             evaluated = False
             if eval_fn is not None and rounds_done(state) % eval_every == 0:
@@ -538,25 +581,29 @@ def budget_train_loop(*, state, max_rounds: int, eval_fn: Callable | None,
                 pending = (build_chunk(next_start, next_n), next_n)
 
         deferred = None
-        if n < chunk_rounds:
-            # tail chunk (budget/max_rounds edge): drive the rows through
-            # the per-round path — the single compiled round is reused for
-            # any tail size, instead of paying a one-shot XLA compile of a
-            # fresh n-round scan for a few rounds
-            recs = []
-            for r in range(n):
-                state, rec = run_tail(state, chunk, r)
-                recs.append(rec)
-        else:
-            try:
-                state, recs = run_chunk(state, chunk, n, build_next)
-            except PrefetchFailed as pf:
-                # the sampler failed building the NEXT chunk; keep the
-                # completed chunk's state/records, re-raise the original
-                # error after recording them (the per-round driver raises
-                # at the same point: after round r, before batch r+1)
-                state, recs, deferred = pf.state, pf.records, pf.__cause__
-        recs = [materialize_record(r) for r in recs]
+        with TraceAnnotation("dppasgd.round", round=rounds_done(state) + 1,
+                             rounds=n):
+            if n < chunk_rounds:
+                # tail chunk (budget/max_rounds edge): drive the rows
+                # through the per-round path — the single compiled round
+                # is reused for any tail size, instead of paying a one-shot
+                # XLA compile of a fresh n-round scan for a few rounds
+                recs = []
+                for r in range(n):
+                    state, rec = run_tail(state, chunk, r)
+                    recs.append(rec)
+            else:
+                try:
+                    state, recs = run_chunk(state, chunk, n, build_next)
+                except PrefetchFailed as pf:
+                    # the sampler failed building the NEXT chunk; keep the
+                    # completed chunk's state/records, re-raise the
+                    # original error after recording them (the per-round
+                    # driver raises at the same point: after round r,
+                    # before batch r+1)
+                    state, recs, deferred = (pf.state, pf.records,
+                                             pf.__cause__)
+            recs = [materialize_record(r) for r in recs]
         history.extend(recs)
         evaluated = False
         if eval_fn is not None and (

@@ -13,6 +13,13 @@ identical privacy algebra. Three granularities:
 After clipping, Gaussian noise b ~ N(0, sigma^2 I_d) is added to the averaged
 gradient — exactly Eq. (7a).
 
+Two ``jax.named_scope`` names mark this boundary in the compiled program's
+op metadata, so a profiler trace attributes device time to it:
+``dppasgd.local_grad`` (the local step's forward and backward) and
+``dppasgd.clip_noise`` (the whole DP path: clip, noise draw and add).
+Scopes change op metadata (at most an instruction's name), never the
+optimized program.
+
 The clip+noise arithmetic — the per-step hot-spot on a constrained device —
 can be routed through the fused ``dp_clip_noise`` kernel via
 ``make_dp_grad_fn(..., kernel_backend=...)`` (see
@@ -32,6 +39,17 @@ from repro.utils.tree import (
     tree_scale,
     tree_sq_norm,
 )
+
+LOCAL_GRAD_SCOPE = "dppasgd.local_grad"
+CLIP_NOISE_SCOPE = "dppasgd.clip_noise"
+
+
+def _scoped(name: str, fn: Callable) -> Callable:
+    """``fn`` traced under ``jax.named_scope(name)``."""
+    def run(*args):
+        with jax.named_scope(name):
+            return fn(*args)
+    return run
 
 
 def clip_tree(grads, clip_norm: float):
@@ -69,7 +87,7 @@ def make_dp_grad_fn(
     legacy per-leaf jnp path. Both draw the noise from the identical
     per-leaf key stream, so the choice only changes arithmetic order.
     """
-    vg_fn = jax.value_and_grad(loss_fn)
+    vg_fn = _scoped(LOCAL_GRAD_SCOPE, jax.value_and_grad(loss_fn))
 
     if kernel_backend is not None:
         from repro.kernels.dispatch import resolve_backend
@@ -93,6 +111,9 @@ def make_dp_grad_fn(
         def _clip_noise(g, key, sigma):
             clipped, norm = clip_tree(g, clip_norm)
             return tree_add_noise(key, clipped, sigma), norm
+
+    _clip = _scoped(CLIP_NOISE_SCOPE, _clip)
+    _clip_noise = _scoped(CLIP_NOISE_SCOPE, _clip_noise)
 
     def _one_microbatch(params, mb):
         loss, g = vg_fn(params, mb)
@@ -145,7 +166,8 @@ def make_dp_grad_fn(
                 loss, pre_norm = jnp.mean(losses), jnp.mean(norms)
         # microbatch paths clip per microbatch (kernel when selected) and
         # noise the averaged gradient once, per the Eq. 7a mechanism
-        noisy = tree_add_noise(key, clipped, sigma)
+        with jax.named_scope(CLIP_NOISE_SCOPE):
+            noisy = tree_add_noise(key, clipped, sigma)
         metrics = {"loss": loss, "grad_norm_preclip": pre_norm}
         return noisy, metrics
 
@@ -154,7 +176,7 @@ def make_dp_grad_fn(
 
 def make_plain_grad_fn(loss_fn: Callable) -> Callable:
     """Non-private gradient with the same signature (sigma ignored)."""
-    vg_fn = jax.value_and_grad(loss_fn)
+    vg_fn = _scoped(LOCAL_GRAD_SCOPE, jax.value_and_grad(loss_fn))
 
     def plain_grad(params, batch, key, sigma):
         del key, sigma

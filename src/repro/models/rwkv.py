@@ -20,6 +20,8 @@ from repro.models.layers import _dense_init
 from repro.models.scan_utils import maybe_map, maybe_scan
 from repro.models.sharding import shard_hint
 
+WKV_SCAN_SCOPE = "rwkv6.wkv_scan"
+
 
 def init_rwkv6_timemix(key, d_model: int, headdim: int = 64, lora_rank: int = 32,
                        dtype=jnp.float32):
@@ -204,13 +206,16 @@ def rwkv6_timemix_forward_state(params, x, headdim: int = 64,
     x_prev = _token_shift(x)
     r, k, v, g, w, log_decay = _tm_inputs(params, x, x_prev)
     heads = lambda t: t.reshape(t.shape[0], t.shape[1], n_heads, headdim)
-    if chunk:
-        y, s_final = wkv6_chunked(heads(r), heads(k), heads(v),
-                                  heads(log_decay), params["bonus_u"],
-                                  chunk=chunk, unroll=unroll)
-    else:
-        y, s_final = wkv6_scan(heads(r), heads(k), heads(v), heads(w),
-                               params["bonus_u"], unroll=unroll)
+    # the recurrence's ops (and, through the transpose, their backward)
+    # carry this scope in the profiler trace
+    with jax.named_scope(WKV_SCAN_SCOPE):
+        if chunk:
+            y, s_final = wkv6_chunked(heads(r), heads(k), heads(v),
+                                      heads(log_decay), params["bonus_u"],
+                                      chunk=chunk, unroll=unroll)
+        else:
+            y, s_final = wkv6_scan(heads(r), heads(k), heads(v), heads(w),
+                                   params["bonus_u"], unroll=unroll)
     out = _tm_output(params, y.astype(x.dtype), g, d_model)
     return out, {"wkv": s_final, "tm_last": x[:, -1:]}
 
