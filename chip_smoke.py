@@ -11,9 +11,9 @@ with random weights drawn from ``--seed``:
     python chip_smoke.py --four-chips   # four chips: the mesh comparison
 
 (a) dense DP-PASGD rounds (the ``map`` engine: clients in sequence) with
-    the fused Pallas clip+noise kernel; checks finite losses, moved
-    params, the privacy ledger against a host accountant, and the kernel
-    against its oracle on a real-width gradient;
+    the leaf-wise clip+noise; checks finite losses, moved params, the
+    privacy ledger against a host accountant, and the flat Pallas
+    clip+noise kernel against its oracle on a real-width gradient;
 (b) the federation with participation 0.5 and qsgd compression, which
     puts the quantize_decompress kernel on the chip (one client: see
     ``QSGD_CLIENTS``);
@@ -108,8 +108,8 @@ def train_phase(cfg, *, n_clients: int, batch: int, seq: int, tau: int,
     """Build a federation the way ``launch/train`` does and train it.
 
     Returns the spec, final state and summary plus what was measured:
-    resolved kernel backends, compile and steady seconds, losses, and the
-    host-accountant epsilon for the same schedule.
+    the resolved backends of the round's kernels, compile and steady
+    seconds, losses, and the host-accountant epsilon for the same schedule.
     """
     import jax
     import numpy as np
@@ -129,8 +129,7 @@ def train_phase(cfg, *, n_clients: int, batch: int, seq: int, tau: int,
         cfg, n_clients, tau, batch, seq, sigmas, lr=LR, clip_norm=CLIP,
         delta=DELTA, engine=engine, seed=seed, rng=rng, **pipeline)
     spec = spec.replace(eps_th=EPS, kernel_backend=kernel_backend)
-    backends = {"dp_clip_noise": resolve_backend("dp_clip_noise",
-                                                 kernel_backend)}
+    backends = {}
     if spec.compressor == "qsgd":
         backends["quantize_decompress"] = resolve_backend(
             "quantize_decompress", kernel_backend)
@@ -197,6 +196,7 @@ def kernel_vs_oracle(width: int, backend: str, seed: int) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
+    from repro.kernels.dispatch import resolve_backend
     from repro.kernels.ops import dp_clip_noise_flat
 
     sigma = 0.01
@@ -206,6 +206,7 @@ def kernel_vs_oracle(width: int, backend: str, seed: int) -> dict:
     got, got_norm = dp_clip_noise_flat(g, noise, CLIP, sigma, backend=backend)
     want, want_norm = dp_clip_noise_flat(g, noise, CLIP, sigma, backend="ref")
     np.testing.assert_allclose(float(got_norm), float(want_norm), rtol=1e-5)
+    resolved = resolve_backend("dp_clip_noise", backend)
 
     @jax.jit
     def excess(got, want, g, noise, norm):
@@ -217,7 +218,7 @@ def kernel_vs_oracle(width: int, backend: str, seed: int) -> dict:
 
     over, worst = excess(got, want, g, noise, want_norm)
     assert float(over) <= 0.0, ("clip+noise off its oracle", float(over))
-    return {"entries": width, "norm": float(want_norm),
+    return {"entries": width, "backend": resolved, "norm": float(want_norm),
             "max_abs_diff": float(worst)}
 
 
@@ -430,7 +431,6 @@ def main(argv=None) -> int:
         dense = train_phase(cfg, n_clients=CLIENTS, batch=BATCH, seq=SEQ,
                             tau=TAU, rounds=ROUNDS, kernel_backend="auto",
                             seed=args.seed, engine=ENGINE)
-        assert dense["backends"]["dp_clip_noise"] == "pallas", dense
         check_training(dense, rounds=ROUNDS, dense=True)
         log(phase="a_dense", engine=dense["engine"],
             backends=dense["backends"], losses=dense["losses"],
@@ -444,8 +444,9 @@ def main(argv=None) -> int:
         model = dense["model"]
         del dense
         width = sum(x.size for x in jax.tree.leaves(served))
-        log(phase="a_kernel_vs_oracle",
-            **kernel_vs_oracle(width, "auto", args.seed))
+        flat = kernel_vs_oracle(width, "auto", args.seed)
+        assert flat["backend"] == "pallas", flat
+        log(phase="a_kernel_vs_oracle", **flat)
 
         comp = train_phase(cfg, n_clients=QSGD_CLIENTS,
                            batch=BATCH, seq=SEQ, tau=TAU, rounds=ROUNDS,

@@ -38,10 +38,11 @@ async specs and point at the ``repro.asyncfl`` drivers, and
 ``engine="auto"`` never resolves to it (async execution is always an
 explicit choice).
 
-Every engine's Eq.-7a clip+noise step runs through the fused
-``dp_clip_noise`` kernel of :mod:`repro.kernels.dispatch` — the backend is
-selected by ``FederationSpec.kernel_backend`` and carried to the gradient
-builder by ``spec.fl_config()``; it is part of ``spec.engine_key()``, so
+Every engine's Eq.-7a clip+noise step is the leaf-wise
+``kernels.ops.dp_clip_noise_tree`` (one fused pass per leaf, the noise
+drawn inside it), whatever ``FederationSpec.kernel_backend`` names; that
+backend, carried by ``spec.fl_config()``, runs the compressor's and the
+resident cohort's kernels. It is part of ``spec.engine_key()``, so
 switching backends recompiles rather than aliasing cached rounds.
 
 Two jitted forms are cached: the single round (:func:`round_fn_for`, per

@@ -54,11 +54,12 @@ class FederationSpec:
     #   axis — the engine for replicas too big for one device. "auto"
     #   resolves to it when ``replica_bytes`` exceeds the per-device budget
     #   (repro.mesh.placement).
-    kernel_backend: str = "auto"    # clip+noise kernel backend
-    #   ("pallas" | "interpret" | "ref" | "auto"): every engine's Eq.-7a
-    #   clip+noise step runs through kernels.dispatch get_kernel(
-    #   "dp_clip_noise") on this backend; "auto" is the Mosaic kernel on
-    #   a TPU (or an error there) and the jnp oracle elsewhere
+    kernel_backend: str = "auto"    # kernel backend
+    #   ("pallas" | "interpret" | "ref" | "auto"): the compressor's and the
+    #   resident cohort's kernels run on it ("auto" is the Mosaic kernel on
+    #   a TPU, or an error there, and the jnp oracle elsewhere); every value
+    #   gives the Eq.-7a step the leaf-wise single-rounding clip+noise
+    #   (kernels.ops.dp_clip_noise_tree), which runs no kernel
 
     # -- aggregation pipeline (Eq. 7b boundary; core/aggregation.py) -------
     participation: float = 1.0      # fraction q in (0,1], or an int count of

@@ -20,11 +20,13 @@ op metadata, so a profiler trace attributes device time to it:
 Scopes change op metadata (at most an instruction's name), never the
 optimized program.
 
-The clip+noise arithmetic — the per-step hot-spot on a constrained device —
-can be routed through the fused ``dp_clip_noise`` kernel via
-``make_dp_grad_fn(..., kernel_backend=...)`` (see
-:mod:`repro.kernels.dispatch`); the default ``None`` keeps the legacy
-per-leaf jnp path bit-for-bit.
+``make_dp_grad_fn(..., kernel_backend=...)`` picks between two clip+noise
+paths: the default ``None`` keeps the legacy ``clip_tree`` +
+``tree_add_noise`` (each leaf rounded to its dtype after the clip and again
+after the noise) bit-for-bit; any backend name selects
+:func:`repro.kernels.ops.dp_clip_noise_tree`, which rounds once and which
+XLA fuses into one pass per leaf, the noise drawn inside it. No Pallas
+kernel runs on this path.
 """
 from __future__ import annotations
 
@@ -81,29 +83,22 @@ def make_dp_grad_fn(
       "scan":  running-sum scan carry — one gradient buffer regardless of the
                microbatch count (§Perf optimization).
 
-    ``kernel_backend`` routes the clip(+noise) arithmetic through the fused
-    ``dp_clip_noise`` kernel of :mod:`repro.kernels.dispatch` on the named
-    backend ("pallas" | "interpret" | "ref" | "auto"); ``None`` keeps the
-    legacy per-leaf jnp path. Both draw the noise from the identical
-    per-leaf key stream, so the choice only changes arithmetic order.
+    ``kernel_backend``: ``None`` keeps the legacy double-rounding path;
+    any other value ("pallas" | "interpret" | "ref" | "auto", all alike
+    here) selects the leaf-wise single-rounding ``dp_clip_noise_tree``.
+    Both draw the noise from the identical per-leaf key stream, so the
+    choice only changes where the result is rounded.
     """
     vg_fn = _scoped(LOCAL_GRAD_SCOPE, jax.value_and_grad(loss_fn))
 
     if kernel_backend is not None:
-        from repro.kernels.dispatch import resolve_backend
         from repro.kernels.ops import dp_clip_noise_tree
 
-        # resolve (and capability-probe) the backend now, at build time:
-        # inside the traced round the probes could not run
-        kernel_backend = resolve_backend("dp_clip_noise", kernel_backend)
-
         def _clip(g):
-            return dp_clip_noise_tree(g, None, clip_norm, 0.0,
-                                      backend=kernel_backend)
+            return dp_clip_noise_tree(g, None, clip_norm, 0.0)
 
         def _clip_noise(g, key, sigma):
-            return dp_clip_noise_tree(g, key, clip_norm, sigma,
-                                      backend=kernel_backend)
+            return dp_clip_noise_tree(g, key, clip_norm, sigma)
     else:
         def _clip(g):
             return clip_tree(g, clip_norm)
@@ -122,7 +117,7 @@ def make_dp_grad_fn(
 
     def dp_grad(params, batch, key, sigma):
         if num_microbatches == 1:
-            # fused hot path: one kernel does norm + scale + noise (Eq. 7a)
+            # one clip+noise of the whole gradient (Eq. 7a)
             loss, g = vg_fn(params, batch)
             noisy, pre_norm = _clip_noise(g, key, sigma)
             metrics = {"loss": loss, "grad_norm_preclip": pre_norm}
@@ -164,7 +159,7 @@ def make_dp_grad_fn(
                 clipped = jax.tree.map(lambda x: jnp.mean(x, axis=0),
                                        clipped_all)
                 loss, pre_norm = jnp.mean(losses), jnp.mean(norms)
-        # microbatch paths clip per microbatch (kernel when selected) and
+        # microbatch paths clip per microbatch (leaf-wise when selected) and
         # noise the averaged gradient once, per the Eq. 7a mechanism
         with jax.named_scope(CLIP_NOISE_SCOPE):
             noisy = tree_add_noise(key, clipped, sigma)

@@ -54,9 +54,10 @@ class FLConfig:
     grad_accumulate: str = "stack"  # "stack" (baseline) | "scan" (§Perf opt)
     average_opt_state: bool = True  # average optimizer state with the models
     vmap_clients: bool = True     # False -> lax.map (sequential clients; CPU sims)
-    kernel_backend: str | None = None  # clip+noise via kernels.dispatch
-    #   None -> legacy pure-jnp path; "pallas"/"interpret"/"ref"/"auto" ->
-    #   the fused dp_clip_noise kernel on that backend (repro.api default)
+    kernel_backend: str | None = None  # clip+noise path (clipping.py)
+    #   None -> legacy double-rounding jnp path; "pallas"/"interpret"/
+    #   "ref"/"auto" -> the leaf-wise single-rounding
+    #   kernels.ops.dp_clip_noise_tree (repro.api default)
 
 
 def make_grad_fn(loss_fn: Callable, cfg: FLConfig) -> Callable:

@@ -16,8 +16,8 @@ runs unchanged.
 :func:`make_shard_map_round` directly; the facade builds the client mesh and
 unifies the call signature with the GSPMD engines. The per-step clip+noise
 inside each shard follows ``FLConfig.kernel_backend`` (see
-:mod:`repro.kernels.dispatch`), identically to the GSPMD engines — the
-Pallas kernel composes under ``shard_map`` + ``vmap`` + ``scan``.
+:mod:`repro.core.clipping`), identically to the GSPMD engines, and composes
+under ``shard_map`` + ``vmap`` + ``scan``.
 
 The built round also composes under the fused multi-round chunking of
 :func:`repro.core.fl.make_chunked_round` (an outer ``lax.scan`` carrying

@@ -1,9 +1,10 @@
 """Pallas compute kernels behind a backend dispatch layer.
 
-The kernels accelerate the compute hot-spots of this repo — above all the
-fused DP clip+noise update (paper Eq. 7a / Eq. 23), the per-step cost that
-dominates DP-PASGD on a resource-constrained device — plus the model-side
-flash attention, RWKV6 WKV scan, and Mamba2 SSD chunk scan.
+The kernels are the fused DP clip+noise update over a flat vector (paper
+Eq. 7a / Eq. 23), the QSGD quantize round trip, the resident cohort
+gather/scatter, and the model-side flash attention, RWKV6 WKV scan and
+Mamba2 SSD chunk scan. The training step's own clip+noise is the leaf-wise
+jnp ``ops.dp_clip_noise_tree``, which XLA fuses per leaf.
 
 Kernel backends
 ---------------
@@ -30,7 +31,7 @@ never quietly runs interpret mode or the oracle. ``rwkv6_scan`` and
 ``mamba2_ssd`` are refused by the TPU compiler today (their block shapes
 break the (8, 128) tiling), so ``auto`` raises for them on a TPU; the
 model code does not call them.
-The training hot path selects its backend declaratively through
+The training path selects its backends declaratively through
 ``FederationSpec(kernel_backend=...)``; :mod:`repro.kernels.ops` carries
 the plain-function wrappers. ``register_kernel`` adds new kernels without
 touching any call site.

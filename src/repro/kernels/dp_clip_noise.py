@@ -1,5 +1,10 @@
-"""Fused DP clip+noise Pallas kernel — the per-step hot-spot of DP-PASGD
-(Eq. 7a touches every gradient element twice: norm pass + scale/noise pass).
+"""Fused DP clip+noise Pallas kernel over one flat vector (Eq. 7a touches
+every gradient element twice: norm pass + scale/noise pass).
+
+It is the flat-vector primitive (``ops.dp_clip_noise_flat``), not the
+training path's: with the noise as an operand it moves 16 bytes per f32
+element, where the leaf-wise jnp form that training uses
+(``ops.dp_clip_noise_tree``) draws the noise inside its one fused pass.
 
 Two kernels over the flat vector laid out as ``(rows, 128)`` lane tiles and
 streamed in ``(block_rows, 128)`` VMEM blocks:

@@ -6,6 +6,10 @@ Mosaic-compiled Pallas on a TPU — or an error there, never a silent
 substitute — and the pure-jnp oracle everywhere else. ``backend=
 "interpret"`` runs the Pallas body as jax ops, the CPU rehearsal of the
 TPU kernel.
+
+``dp_clip_noise_tree``, the DP-PASGD step's clip+noise, is plain jnp and
+dispatches nothing: the flat ``dp_clip_noise`` kernel stays available as
+``dp_clip_noise_flat``.
 """
 from __future__ import annotations
 
@@ -14,6 +18,7 @@ import jax.numpy as jnp
 
 from repro.kernels.dispatch import get_kernel
 from repro.kernels.dp_clip_noise import DEFAULT_BLOCK
+from repro.utils.tree import tree_sq_norm
 
 
 def dp_clip_noise_flat(g, noise, clip_norm, sigma, block: int = DEFAULT_BLOCK,
@@ -23,36 +28,35 @@ def dp_clip_noise_flat(g, noise, clip_norm, sigma, block: int = DEFAULT_BLOCK,
                                                 block=block)
 
 
-def dp_clip_noise_tree(grads, key, clip_norm, sigma,
-                       block: int = DEFAULT_BLOCK, backend: str = "auto"):
-    """Tree-level fused clip+noise: flatten -> kernel -> unflatten.
+def dp_clip_noise_tree(grads, key, clip_norm, sigma):
+    """Clip a gradient pytree to global L2 norm ``clip_norm`` and add
+    N(0, sigma^2) noise, leaf by leaf, in each leaf's own shape and dtype.
 
-    Drop-in replacement for core.clipping ``clip_tree`` + ``tree_add_noise``;
-    preserves each leaf's dtype. ``key=None`` skips the noise draw entirely
-    (clip-only kernel lowering — no noise buffer materialized). The noise is
-    drawn per leaf from split keys — the same stream structure as
-    ``tree_add_noise`` — so swapping backends (or swapping the legacy path
-    for this one) only changes arithmetic order, never the sampled noise.
+    Returns (tree, pre-clip norm). Each output leaf is
+    ``(g * scale + sigma * normal(k_i, g.shape)).astype(g.dtype)``, the
+    product and sum in f32 and rounded once, with ``k_i`` the i-th of
+    ``jax.random.split(key, n_leaves)`` in ``jax.tree.flatten`` order: the
+    noise stream of ``tree_add_noise``. ``key=None`` clips only.
+
+    Nothing is flattened: XLA fuses each leaf's draw, scale and add into
+    one elementwise pass that reads the gradient and writes the result, so
+    neither the noise nor an f32 copy of the leaf reaches memory. The flat
+    ``dp_clip_noise`` kernel would need the noise as an operand, which
+    moves more bytes than this pass does.
     """
     leaves, treedef = jax.tree.flatten(grads)
-    sizes = [x.size for x in leaves]
-    flat = jnp.concatenate([x.reshape(-1).astype(jnp.float32)
-                            for x in leaves])
+    norm = jnp.sqrt(tree_sq_norm(leaves))
+    scale = jnp.minimum(1.0, clip_norm / jnp.maximum(norm, 1e-12))
     if key is None:
-        noise = None
+        out = [(g.astype(jnp.float32) * scale).astype(g.dtype)
+               for g in leaves]
     else:
         keys = jax.random.split(key, len(leaves))
-        noise = jnp.concatenate(
-            [jax.random.normal(k, x.shape, jnp.float32).reshape(-1)
-             for k, x in zip(keys, leaves)])
-    out, norm = dp_clip_noise_flat(flat, noise, clip_norm, sigma,
-                                   block=block, backend=backend)
-    news = []
-    off = 0
-    for x, n in zip(leaves, sizes):
-        news.append(out[off:off + n].reshape(x.shape).astype(x.dtype))
-        off += n
-    return jax.tree.unflatten(treedef, news), norm
+        out = [(g.astype(jnp.float32) * scale
+                + sigma * jax.random.normal(k, g.shape, jnp.float32)
+                ).astype(g.dtype)
+               for k, g in zip(keys, leaves)]
+    return jax.tree.unflatten(treedef, out), norm
 
 
 def quantize_decompress_flat(x, u, bits: int, block: int = DEFAULT_BLOCK,

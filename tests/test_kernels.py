@@ -270,12 +270,52 @@ def test_dp_clip_noise_tree_dtype_preservation():
     tree = {"w": jax.random.normal(jax.random.PRNGKey(0), (9, 4),
                                    jnp.bfloat16) * 10,
             "b": jax.random.normal(jax.random.PRNGKey(1), (7,), jnp.float32)}
-    for backend in CPU_BACKENDS:
-        out, norm = dp_clip_noise_tree(tree, jax.random.PRNGKey(2), 1.0, 0.3,
-                                       backend=backend)
-        assert jax.tree.map(lambda x: x.dtype, out) == \
-            jax.tree.map(lambda x: x.dtype, tree)
-        assert float(norm) > 0
+    out, norm = dp_clip_noise_tree(tree, jax.random.PRNGKey(2), 1.0, 0.3)
+    assert jax.tree.map(lambda x: x.dtype, out) == \
+        jax.tree.map(lambda x: x.dtype, tree)
+    assert float(norm) > 0
+
+
+@pytest.mark.parametrize("noise", [True, False], ids=["noise", "clip-only"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_dp_clip_noise_tree_leafwise_formula(dtype, noise):
+    """Each leaf is exactly ``(g * scale + sigma * normal(k_i)).astype``,
+    rounded once, with ``k_i`` the i-th of ``split(key, n_leaves)`` in
+    flatten order (or ``g * scale`` for ``key=None``); the norm is the
+    f32 norm of the whole tree."""
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    tree = {"a": jax.random.normal(ks[0], (33, 7), dtype) * 4,
+            "b": [jax.random.normal(ks[1], (5,), dtype),
+                  jax.random.normal(ks[2], (2, 3, 4), dtype) * 9]}
+    key, sigma, clip = jax.random.PRNGKey(11), 0.7, 1.5
+    got, norm = dp_clip_noise_tree(tree, key if noise else None, clip, sigma)
+
+    leaves = jax.tree.leaves(tree)
+    want_norm = np.sqrt(sum(np.sum(np.square(np.asarray(x, np.float64)))
+                            for x in leaves))
+    np.testing.assert_allclose(float(norm), want_norm, rtol=1e-6)
+    scale = jnp.minimum(1.0, clip / jnp.maximum(norm, 1e-12))
+    keys = jax.random.split(key, len(leaves))
+    for i, (g, y) in enumerate(zip(leaves, jax.tree.leaves(got))):
+        want = g.astype(jnp.float32) * scale
+        if noise:
+            want = want + sigma * jax.random.normal(keys[i], g.shape,
+                                                    jnp.float32)
+        want = want.astype(g.dtype)
+        assert y.dtype == g.dtype and y.shape == g.shape
+        np.testing.assert_array_equal(np.asarray(y, np.float32),
+                                      np.asarray(want, np.float32))
+
+
+def test_dp_clip_noise_tree_builds_no_flat_copy():
+    """The tree path works leaf by leaf: its program concatenates nothing
+    into a flat vector and calls no Pallas kernel."""
+    tree = {"w": jnp.ones((16, 128), jnp.bfloat16),
+            "b": jnp.ones((7,), jnp.float32)}
+    for key in (jax.random.PRNGKey(0), None):
+        jaxpr = str(jax.make_jaxpr(
+            lambda t, s: dp_clip_noise_tree(t, key, 1.0, s))(tree, 0.5))
+        assert "concatenate" not in jaxpr and "pallas_call" not in jaxpr
 
 
 @pytest.mark.parametrize("name", ["dp_clip_noise", "quantize_decompress"])
@@ -305,10 +345,9 @@ def test_dp_clip_noise_tree_matches_core():
             "b": {"c": jax.random.normal(jax.random.PRNGKey(1), (11,))}}
     key = jax.random.PRNGKey(2)
     want, wnorm = clip_tree(tree, 1.0)
-    for backend in CPU_BACKENDS:
-        got, norm = dp_clip_noise_tree(tree, key, 1.0, 0.0, backend=backend)
-        _assert_trees_close(got, want, 1e-5)
-        np.testing.assert_allclose(float(norm), float(wnorm), rtol=1e-5)
+    got, norm = dp_clip_noise_tree(tree, key, 1.0, 0.0)
+    _assert_trees_close(got, want, 1e-5)
+    np.testing.assert_allclose(float(norm), float(wnorm), rtol=1e-5)
 
 
 def test_dp_clip_noise_tree_noise_matches_tree_add_noise():
@@ -321,7 +360,7 @@ def test_dp_clip_noise_tree_noise_matches_tree_add_noise():
     key = jax.random.PRNGKey(5)
     clipped, _ = clip_tree(tree, 1.0)
     want = tree_add_noise(key, clipped, 0.7)
-    got, _ = dp_clip_noise_tree(tree, key, 1.0, 0.7, backend="ref")
+    got, _ = dp_clip_noise_tree(tree, key, 1.0, 0.7)
     _assert_trees_close(got, want, 1e-6)
 
 
