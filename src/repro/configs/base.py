@@ -3,7 +3,8 @@
 An architecture is a stack of *segments*; each segment is ``n_steps``
 repetitions (lax.scan with stacked params) of a ``pattern`` of layers. This
 lets heterogeneous archs (gemma3's 5:1 local:global, llama4's 3:1
-chunked:global iRoPE, zamba2's shared-attention-every-6-mamba) compile as a
+chunked:global iRoPE, zamba2's Mamba2 layers with a shared transformer
+block every ~6) compile as a
 small number of scans instead of L unrolled layers.
 """
 from __future__ import annotations
@@ -15,10 +16,14 @@ _REGISTRY: dict[str, "ArchConfig"] = {}
 
 @dataclass(frozen=True)
 class LayerSpec:
-    mixer: str = "attn"        # attn | mamba2 | rwkv6 | shared_attn
-    ffn: str = "mlp"           # mlp | moe | rwkv_cm | none | shared_mlp
+    mixer: str = "attn"        # attn | mamba2 | rwkv6
+    ffn: str = "mlp"           # mlp | moe | rwkv_cm | none
     attn_kind: str = "full"    # full | swa | chunk
     use_rope: bool = True
+    # zamba2 hybrid layer: index of the shared transformer block whose
+    # output (through this layer's adapter and ``linear``) is added to the
+    # mixer's input; -1 for none
+    shared_block: int = -1
 
 
 @dataclass(frozen=True)
@@ -42,6 +47,7 @@ class ArchConfig:
     head_dim: int = 0          # 0 -> d_model // n_heads
     qkv_bias: bool = False
     rope_theta: float = 1e4
+    norm_eps: float = 1e-6     # every RMSNorm's epsilon
     window: int = 0            # sliding-window size (swa layers)
     chunk: int = 0             # chunk size (chunked-attention layers)
     embed_scale: bool = False  # gemma-style sqrt(d) embedding scale
@@ -55,14 +61,16 @@ class ArchConfig:
     # --- SSM (mamba2) ---
     ssm_state: int = 0
     ssm_headdim: int = 64
-    ssm_expand: int = 2
+    ssm_heads: int = 0         # d_inner = ssm_heads * ssm_headdim
+    ssm_ngroups: int = 1       # B/C groups; heads split evenly over them
     conv_kernel: int = 4
     ssd_chunk: int = 128
     # --- RWKV ---
     rwkv_headdim: int = 64
     rwkv_chunk: int = 0        # 0 = per-token scan; >0 = chunk-parallel WKV6
-    # --- shared attention block (zamba2) ---
-    lora_rank: int = 0
+    lora_rank: int = 0         # RWKV-6 time-decay LoRA rank
+    # --- shared transformer blocks (zamba2) ---
+    adapter_rank: int = 0      # per-invocation adapter on the MLP's gate/up
     # --- modality frontend stub (vlm / audio) ---
     prefix_len: int = 0        # precomputed patch/frame embeddings length
     # --- misc ---
@@ -92,6 +100,11 @@ class ArchConfig:
             for _ in range(seg.n_steps):
                 out.extend(seg.pattern)
         return out
+
+    @property
+    def n_shared_blocks(self) -> int:
+        """Shared transformer blocks the layers invoke (zamba2)."""
+        return 1 + max(ls.shared_block for ls in self.layer_specs())
 
     def count_mixers(self) -> dict[str, int]:
         counts: dict[str, int] = {}
@@ -137,6 +150,12 @@ def patterned_segments(n_layers: int, pattern: tuple[LayerSpec, ...]
     return tuple(segs)
 
 
+def _smoke_pattern(pattern: tuple[LayerSpec, ...]) -> tuple[LayerSpec, ...]:
+    """The first 4 layers, and every layer that invokes a shared block."""
+    return pattern[:4] + tuple(ls for ls in pattern[4:]
+                               if ls.shared_block >= 0)
+
+
 def smoke_variant(cfg: ArchConfig) -> ArchConfig:
     """Reduced config of the same family: <=2 segment steps, d_model<=256,
     <=4 experts — runnable on CPU for the per-arch smoke tests."""
@@ -150,7 +169,7 @@ def smoke_variant(cfg: ArchConfig) -> ArchConfig:
     for seg in cfg.segments:
         if total >= 2:
             break
-        segs.append(Segment(n_steps=1, pattern=seg.pattern[:4]))
+        segs.append(Segment(n_steps=1, pattern=_smoke_pattern(seg.pattern)))
         total += 1
     return replace(
         cfg,
@@ -170,10 +189,12 @@ def smoke_variant(cfg: ArchConfig) -> ArchConfig:
         capacity_factor=4.0 if cfg.n_experts else cfg.capacity_factor,
         ssm_state=min(cfg.ssm_state, 16) if cfg.ssm_state else 0,
         ssm_headdim=32 if cfg.ssm_state else cfg.ssm_headdim,
+        ssm_heads=min(cfg.ssm_heads, 16),
         rwkv_headdim=32,
         window=min(cfg.window, 16) if cfg.window else 0,
         chunk=min(cfg.chunk, 16) if cfg.chunk else 0,
         lora_rank=min(cfg.lora_rank, 4) if cfg.lora_rank else 0,
+        adapter_rank=min(cfg.adapter_rank, 8),
         prefix_len=min(cfg.prefix_len, 8) if cfg.prefix_len else 0,
         block_q=8,
         ssd_chunk=8,

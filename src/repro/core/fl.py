@@ -176,15 +176,16 @@ def make_round_step(loss_fn: Callable, optimizer: Optimizer, cfg: FLConfig,
                                          sigmas)
         if topology == "full_average":
             # ---- Eq. (7b): periodic global averaging ----------------------
-            avg = tree_mean_over_axis0(new_p)
-            new_p = tree_broadcast_axis0(avg, cfg.n_clients)
-            if cfg.average_opt_state:
-                # keep_dtype: int leaves (step counters) must come back as
-                # ints or the round is carry-unstable under scan chunking,
-                # undonatable, and retraced on its second call
-                new_s = tree_broadcast_axis0(
-                    tree_mean_over_axis0(new_s, keep_dtype=True),
-                    cfg.n_clients)
+            with jax.named_scope("dppasgd.average"):
+                avg = tree_mean_over_axis0(new_p)
+                new_p = tree_broadcast_axis0(avg, cfg.n_clients)
+                if cfg.average_opt_state:
+                    # keep_dtype: int leaves (step counters) must come back
+                    # as ints or the round is carry-unstable under scan
+                    # chunking, undonatable, and retraced on its second call
+                    new_s = tree_broadcast_axis0(
+                        tree_mean_over_axis0(new_s, keep_dtype=True),
+                        cfg.n_clients)
         ms = jax.tree.map(jnp.mean, ms)
         return new_p, new_s, ms
 

@@ -102,13 +102,16 @@ def make_shard_map_round(loss_fn: Callable, optimizer: Optimizer,
             # (local block mean first, so the pmean moves C/n_shards fewer
             # bytes than an all-gather would).
             pmean = lambda x: jax.lax.pmean(x, axis_name=client_axis)
-            avg = jax.tree.map(lambda x: pmean(jnp.mean(x, axis=0)), new_p)
-            new_p = tree_broadcast_axis0(avg, block)
-            if cfg.average_opt_state:
-                avg_s = jax.tree.map(
-                    lambda x: pmean(jnp.mean(x.astype(jnp.float32), axis=0)
-                                    ).astype(x.dtype), new_s)
-                new_s = tree_broadcast_axis0(avg_s, block)
+            with jax.named_scope("dppasgd.average"):
+                avg = jax.tree.map(lambda x: pmean(jnp.mean(x, axis=0)),
+                                   new_p)
+                new_p = tree_broadcast_axis0(avg, block)
+                if cfg.average_opt_state:
+                    avg_s = jax.tree.map(
+                        lambda x: pmean(jnp.mean(x.astype(jnp.float32),
+                                                 axis=0)).astype(x.dtype),
+                        new_s)
+                    new_s = tree_broadcast_axis0(avg_s, block)
         ms = jax.tree.map(lambda x: jax.lax.pmean(x, client_axis), ms)
         return new_p, new_s, ms
 

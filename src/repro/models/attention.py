@@ -24,13 +24,17 @@ NEG_INF = -1e30
 
 
 def init_attention(key, d_model: int, n_heads: int, n_kv_heads: int,
-                   head_dim: int, qkv_bias: bool = False, dtype=jnp.float32):
+                   head_dim: int, qkv_bias: bool = False, dtype=jnp.float32,
+                   d_out: int | None = None):
+    """Projections from ``d_model`` and, through ``wo``, back to ``d_out``
+    (default ``d_model``)."""
     k1, k2, k3, k4 = jax.random.split(key, 4)
     params = {
         "wq": _dense_init(k1, (d_model, n_heads, head_dim), 0, dtype),
         "wk": _dense_init(k2, (d_model, n_kv_heads, head_dim), 0, dtype),
         "wv": _dense_init(k3, (d_model, n_kv_heads, head_dim), 0, dtype),
-        "wo": _dense_init(k4, (n_heads, head_dim, d_model), 2, dtype),
+        "wo": _dense_init(k4, (n_heads, head_dim, d_out or d_model), 2,
+                          dtype),
     }
     axes = {
         "wq": ("fsdp", "tp", None),
@@ -67,18 +71,20 @@ def _project_qkv(params, x, positions, use_rope: bool, rope_theta: float):
     return q, k, v
 
 
-def _sdpa(q, k, v, mask):
+def _sdpa(q, k, v, mask, scale=None):
     """Grouped scaled-dot-product attention on one (q-block, kv-block).
 
     q (B, Sq, H, hd); k/v (B, Skv, KV, hd); mask broadcastable to
-    (B, 1, 1, Sq, Skv). Softmax in fp32.
+    (B, 1, 1, Sq, Skv). Softmax in fp32 of the scores times ``scale``
+    (None: 1 / sqrt(hd)).
     """
     b, sq, h, hd = q.shape
     kv = k.shape[2]
     g = h // kv
     qg = q.reshape(b, sq, kv, g, hd)
     scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, k).astype(jnp.float32)
-    scores = scores / math.sqrt(hd)
+    scores = (scores / math.sqrt(hd) if scale is None
+              else scores * scale)
     scores = jnp.where(mask, scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bkgqs,bskd->bqkgd", probs.astype(v.dtype), v)
@@ -87,7 +93,7 @@ def _sdpa(q, k, v, mask):
 
 def blocked_causal_attention(q, k, v, *, window: int = 0, block_q: int = 512,
                              q_start: int = 0, causal_buckets: bool = False,
-                             unroll: bool = False):
+                             unroll: bool = False, scale=None):
     """Causal (optionally sliding-window) attention, tiled over q blocks.
 
     window == 0 -> full causal. window == W -> attend to the last W positions
@@ -100,7 +106,7 @@ def blocked_causal_attention(q, k, v, *, window: int = 0, block_q: int = 512,
     """
     if causal_buckets and not window and q_start == 0:
         return _bucketed_causal_attention(q, k, v, block_q=block_q,
-                                          unroll=unroll)
+                                          unroll=unroll, scale=scale)
     b, sq, h, hd = q.shape
     skv = k.shape[1]
     bq = min(block_q, sq)
@@ -132,7 +138,7 @@ def blocked_causal_attention(q, k, v, *, window: int = 0, block_q: int = 512,
         if window:
             mask &= k_pos[None, :] > q_pos[:, None] - window
         mask = mask[None, None, None]
-        return _sdpa(qb, kb, vb, mask)
+        return _sdpa(qb, kb, vb, mask, scale)
 
     out = maybe_map(one_block, jnp.arange(n_blocks), unroll)  # (nb,B,bq,H,hd)
     out = jnp.moveaxis(out, 0, 1).reshape(b, n_blocks * bq, h, hd)
@@ -140,7 +146,7 @@ def blocked_causal_attention(q, k, v, *, window: int = 0, block_q: int = 512,
 
 
 def _bucketed_causal_attention(q, k, v, *, block_q: int,
-                               unroll: bool = False):
+                               unroll: bool = False, scale=None):
     """Causal attention with power-of-two kv buckets (static shapes).
 
     q block i needs kv[0 : (i+1) * bq]. Blocks with i+1 in (2^b/2, 2^b] share
@@ -167,7 +173,7 @@ def _bucketed_causal_attention(q, k, v, *, block_q: int,
             qb = jax.lax.dynamic_slice_in_dim(q, qs, bq, axis=1)
             q_pos = qs + jnp.arange(bq)
             mask = (q_pos[:, None] >= k_pos[None, :])[None, None, None]
-            return _sdpa(qb, kb, vb, mask)
+            return _sdpa(qb, kb, vb, mask, scale)
 
         one_block = jax.checkpoint(one_block)
         out = maybe_map(one_block, jnp.arange(count), unroll)
@@ -177,14 +183,15 @@ def _bucketed_causal_attention(q, k, v, *, block_q: int,
     return jnp.concatenate(outs, axis=1)
 
 
-def chunked_causal_attention(q, k, v, chunk: int, unroll: bool = False):
+def chunked_causal_attention(q, k, v, chunk: int, unroll: bool = False,
+                             scale=None):
     """Llama4-style chunked attention: tokens attend causally only within
     their own chunk. O(S * chunk)."""
     b, s, h, hd = q.shape
     if s <= chunk:
         pos = jnp.arange(s)
         mask = (pos[:, None] >= pos[None, :])[None, None, None]
-        return _sdpa(q, k, v, mask)
+        return _sdpa(q, k, v, mask, scale)
     assert s % chunk == 0, f"seq {s} not divisible by chunk {chunk}"
     n = s // chunk
     kv_h = k.shape[2]
@@ -194,7 +201,7 @@ def chunked_causal_attention(q, k, v, chunk: int, unroll: bool = False):
         qc, kc, vc = args
         pos = jnp.arange(chunk)
         mask = (pos[:, None] >= pos[None, :])[None, None, None]
-        return _sdpa(qc, kc, vc, mask)
+        return _sdpa(qc, kc, vc, mask, scale)
 
     qc = jnp.moveaxis(q.reshape(b, n, chunk, h, hd), 1, 0)
     kc = jnp.moveaxis(k.reshape(b, n, chunk, kv_h, hd), 1, 0)
@@ -206,12 +213,13 @@ def chunked_causal_attention(q, k, v, chunk: int, unroll: bool = False):
 def attention_forward(params, x, positions, *, kind: str = "full",
                       window: int = 0, chunk: int = 0, use_rope: bool = True,
                       rope_theta: float = 1e4, block_q: int = 512,
-                      causal_buckets: bool = False, unroll: bool = False):
+                      causal_buckets: bool = False, unroll: bool = False,
+                      scale=None):
     """Full-sequence attention (train / prefill). Returns (B, S, d)."""
     out, _ = attention_forward_kv(
         params, x, positions, kind=kind, window=window, chunk=chunk,
         use_rope=use_rope, rope_theta=rope_theta, block_q=block_q,
-        causal_buckets=causal_buckets, unroll=unroll)
+        causal_buckets=causal_buckets, unroll=unroll, scale=scale)
     return out
 
 
@@ -219,19 +227,21 @@ def attention_forward_kv(params, x, positions, *, kind: str = "full",
                          window: int = 0, chunk: int = 0,
                          use_rope: bool = True, rope_theta: float = 1e4,
                          block_q: int = 512, causal_buckets: bool = False,
-                         unroll: bool = False):
+                         unroll: bool = False, scale=None):
     """Like attention_forward but also returns the (k, v) pair for prefill
-    cache construction."""
+    cache construction. ``scale`` multiplies the scores (None: 1/sqrt(hd))."""
     q, k, v = _project_qkv(params, x, positions, use_rope, rope_theta)
     if kind == "full":
         ctxv = blocked_causal_attention(q, k, v, window=0, block_q=block_q,
                                         causal_buckets=causal_buckets,
-                                        unroll=unroll)
+                                        unroll=unroll, scale=scale)
     elif kind == "swa":
         ctxv = blocked_causal_attention(q, k, v, window=window,
-                                        block_q=block_q, unroll=unroll)
+                                        block_q=block_q, unroll=unroll,
+                                        scale=scale)
     elif kind == "chunk":
-        ctxv = chunked_causal_attention(q, k, v, chunk=chunk, unroll=unroll)
+        ctxv = chunked_causal_attention(q, k, v, chunk=chunk, unroll=unroll,
+                                        scale=scale)
     else:
         raise ValueError(f"unknown attention kind {kind}")
     wo = shard_hint(params["wo"], "tp", None, "fsdp")
@@ -299,7 +309,7 @@ def init_paged_kv_cache(n_blocks: int, block_size: int, n_kv_heads: int,
 def paged_decode_attention(params, x, cache, table, pos, *,
                            kind: str = "full", window: int = 0,
                            chunk: int = 0, use_rope: bool = True,
-                           rope_theta: float = 1e4):
+                           rope_theta: float = 1e4, scale=None):
     """One-token decode over B independent slots of a paged KV cache.
 
     x (B, 1, d); cache {"k"/"v": (NB, bs, KV, hd)} block pool; table
@@ -330,7 +340,7 @@ def paged_decode_attention(params, x, cache, table, pos, *,
     elif kind == "chunk":
         valid &= p[None, :] >= (pos[:, None] // chunk) * chunk
     mask = valid[:, None, None, None, :]
-    ctxv = _sdpa(q, kb, vb, mask)
+    ctxv = _sdpa(q, kb, vb, mask, scale)
     wo = shard_hint(params["wo"], "tp", None, "fsdp")
     out = jnp.einsum("bshk,hkd->bsd", ctxv, wo)
     return shard_hint(out, "batch", "seq", None), {"k": ck, "v": cv}
@@ -338,7 +348,7 @@ def paged_decode_attention(params, x, cache, table, pos, *,
 
 def decode_attention(params, x, cache, pos, *, kind: str = "full",
                      window: int = 0, chunk: int = 0, use_rope: bool = True,
-                     rope_theta: float = 1e4):
+                     rope_theta: float = 1e4, scale=None):
     """One-token decode. x (B, 1, d); pos scalar int32 = index of this token.
     Returns (out (B,1,d), updated cache)."""
     positions = jnp.full((1,), pos, jnp.int32)
@@ -359,7 +369,7 @@ def decode_attention(params, x, cache, pos, *, kind: str = "full",
     elif kind == "chunk":
         valid &= entry_pos >= (pos // chunk) * chunk
     mask = valid[None, None, None, None, :]
-    ctxv = _sdpa(q, ck, cv, mask)
+    ctxv = _sdpa(q, ck, cv, mask, scale)
     wo = shard_hint(params["wo"], "tp", None, "fsdp")
     out = jnp.einsum("bshk,hkd->bsd", ctxv, wo)
     return shard_hint(out, "batch", "seq", None), {"k": ck, "v": cv}

@@ -1,13 +1,19 @@
-"""Mamba2 (SSD) mixer — used by zamba2 (paper-assigned hybrid arch).
+"""Mamba2 (SSD) mixer, as Zamba2 uses it [arXiv:2405.21060, 2411.15242].
 
-State-space recurrence per head h with state (P, N):
-    H_t = exp(dt_t * A_h) * H_{t-1} + dt_t * x_t (P) outer B_t (N)
-    y_t = H_t @ C_t + D_h * x_t
-Training/prefill uses the chunked SSD algorithm (intra-chunk quadratic form +
-inter-chunk state scan); decode is the plain one-step recurrence.
+Per head h with state (P, N), reading group g(h) = h // (H / G) of B and C:
+    H_t = exp(dt_t * A_h) * H_{t-1} + dt_t * x_t (P) outer B_t,g (N)
+    y_t = H_t @ C_t,g + D_h * x_t
+``w_in`` splits into z, x, B, C and dt (B and C of G x N each); x, B and C
+pass a causal depthwise conv with bias and a SiLU; dt = softplus(dt +
+dt_bias), A = -exp(a_log). The output is ``w_out(norm(y * silu(z)))``, an
+RMSNorm over each group's d_inner / G channels.
 
-Shapes: d_inner = expand * d_model; H = d_inner / headdim (P = headdim);
-B/C shared across heads (single group), state size N = cfg.ssm_state.
+Training and prefill use the chunked SSD algorithm (intra-chunk quadratic
+form + inter-chunk state scan) at the configured chunk; decode is the plain
+one-step recurrence.
+
+Shapes: d_inner = H * P (P = headdim); the conv runs over
+d_inner + 2 * G * N channels.
 """
 from __future__ import annotations
 
@@ -19,17 +25,17 @@ from repro.models.scan_utils import maybe_scan
 from repro.models.sharding import shard_hint
 
 
-def init_mamba2(key, d_model: int, d_state: int, headdim: int = 64,
-                expand: int = 2, conv_kernel: int = 4, dtype=jnp.float32):
-    d_inner = expand * d_model
-    n_heads = d_inner // headdim
-    ks = jax.random.split(key, 4)
+def init_mamba2(key, d_model: int, d_state: int, headdim: int, n_heads: int,
+                n_groups: int, conv_kernel: int = 4, dtype=jnp.float32):
+    d_inner = n_heads * headdim
+    conv_dim = d_inner + 2 * n_groups * d_state
+    ks = jax.random.split(key, 3)
     params = {
         # fused input projection: [z, x, B, C, dt]
-        "w_in": _dense_init(ks[0], (d_model, 2 * d_inner + 2 * d_state + n_heads),
+        "w_in": _dense_init(ks[0], (d_model, d_inner + conv_dim + n_heads),
                             0, dtype),
-        "conv_w": _dense_init(ks[1], (conv_kernel, d_inner + 2 * d_state), 0,
-                              dtype),
+        "conv_w": _dense_init(ks[1], (conv_kernel, conv_dim), 0, dtype),
+        "conv_b": jnp.zeros((conv_dim,), dtype),
         "a_log": jnp.zeros((n_heads,), jnp.float32),        # A = -exp(a_log)
         "dt_bias": jnp.zeros((n_heads,), jnp.float32),
         "d_skip": jnp.ones((n_heads,), jnp.float32),
@@ -39,6 +45,7 @@ def init_mamba2(key, d_model: int, d_state: int, headdim: int = 64,
     axes = {
         "w_in": ("fsdp", "tp"),
         "conv_w": (None, "tp"),
+        "conv_b": ("tp",),
         "a_log": ("tp",),
         "dt_bias": ("tp",),
         "d_skip": ("tp",),
@@ -48,28 +55,40 @@ def init_mamba2(key, d_model: int, d_state: int, headdim: int = 64,
     return params, axes
 
 
-def _split_proj(proj, d_inner, d_state, n_heads):
-    z = proj[..., :d_inner]
-    xbc = proj[..., d_inner:2 * d_inner + 2 * d_state]
-    dt = proj[..., 2 * d_inner + 2 * d_state:]
-    return z, xbc, dt
+def _split_proj(proj, d_inner: int, n_groups: int, d_state: int):
+    """(z, xBC, dt) of the fused input projection."""
+    conv_end = 2 * d_inner + 2 * n_groups * d_state
+    return proj[..., :d_inner], proj[..., d_inner:conv_end], proj[..., conv_end:]
 
 
-def _causal_conv(xbc, conv_w):
-    """Depthwise causal conv over seq. xbc (B,S,C); conv_w (K,C)."""
+def _split_xbc(xbc, d_inner: int, n_groups: int, d_state: int):
+    """(x, B, C) of the conv output; B and C as (..., G, N)."""
+    gn = n_groups * d_state
+    lead = xbc.shape[:-1]
+    b_in = xbc[..., d_inner:d_inner + gn].reshape(*lead, n_groups, d_state)
+    c_in = xbc[..., d_inner + gn:].reshape(*lead, n_groups, d_state)
+    return xbc[..., :d_inner], b_in, c_in
+
+
+def _causal_conv(xbc, conv_w, conv_b):
+    """Depthwise causal conv over seq with bias, then SiLU, in float32.
+    xbc (B,S,C); conv_w (K,C); conv_b (C,)."""
     k = conv_w.shape[0]
-    pad = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0)))
-    out = sum(pad[:, i:i + xbc.shape[1]] * conv_w[i] for i in range(k))
-    return jax.nn.silu(out.astype(jnp.float32)).astype(xbc.dtype)
+    pad = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0))).astype(jnp.float32)
+    w = conv_w.astype(jnp.float32)
+    out = sum(pad[:, i:i + xbc.shape[1]] * w[i] for i in range(k))
+    return jax.nn.silu(out + conv_b.astype(jnp.float32))
 
 
-def _gated_out(params, y, z, d_model):
+def _gated_out(params, y, z, n_groups: int, eps: float):
+    """``w_out(norm(y * silu(z)))``: y (B,S,H,P), z (B,S,d_inner); the
+    RMSNorm runs over each group's channels."""
     b, s = y.shape[:2]
-    y = y.reshape(b, s, -1)
-    # RMS-normed gating (Mamba2 uses grouped RMSNorm before out-proj)
-    y32 = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    y32 = y.reshape(b, s, n_groups, -1).astype(jnp.float32) * jax.nn.silu(
+        z.astype(jnp.float32)).reshape(b, s, n_groups, -1)
     var = jnp.mean(jnp.square(y32), axis=-1, keepdims=True)
-    y32 = y32 * jax.lax.rsqrt(var + 1e-6) * params["norm_scale"].astype(jnp.float32)
+    y32 = (y32 * jax.lax.rsqrt(var + eps)).reshape(b, s, -1)
+    y32 = y32 * params["norm_scale"].astype(jnp.float32)
     w_out = shard_hint(params["w_out"], "tp", "fsdp")
     out = jnp.einsum("bsf,fd->bsd", y32.astype(w_out.dtype), w_out)
     return shard_hint(out, "batch", "seq", None)
@@ -80,134 +99,137 @@ def ssd_chunked(x, dt, a, b_in, c_in, chunk: int = 128, h0=None,
     """Chunked SSD scan.
 
     x (B,S,H,P); dt (B,S,H) (post-softplus); a (H,) negative;
-    b_in/c_in (B,S,N). Returns (y (B,S,H,P), final state (B,H,P,N))."""
-    bsz, s, h, p = x.shape
-    n = b_in.shape[-1]
-    if s % chunk:
-        raise ValueError(f"seq {s} not divisible by ssd chunk {chunk}")
-    nc = s // chunk
+    b_in/c_in (B,S,G,N), head h reading group h // (H/G).
+    Returns (y (B,S,H,P) float32, final state (B,H,P,N))."""
+    with jax.named_scope("mamba2.ssd_scan"):
+        bsz, s, h, p = x.shape
+        g, n = b_in.shape[-2:]
+        r = h // g
+        if s % chunk:
+            raise ValueError(f"seq {s} not divisible by ssd chunk {chunk}")
+        nc = s // chunk
 
-    xs = x.reshape(bsz, nc, chunk, h, p)
-    dts = dt.reshape(bsz, nc, chunk, h)
-    bs = b_in.reshape(bsz, nc, chunk, n)
-    cs = c_in.reshape(bsz, nc, chunk, n)
+        xs = x.reshape(bsz, nc, chunk, g, r, p)
+        dts = dt.reshape(bsz, nc, chunk, g, r)
+        bs = b_in.reshape(bsz, nc, chunk, g, n)
+        cs = c_in.reshape(bsz, nc, chunk, g, n)
 
-    # log-decay within chunk: l[t] = cumsum(dt * a)
-    dta = dts * a[None, None, None, :]                     # (B,nc,Q,H)
-    l = jnp.cumsum(dta, axis=2)
-    l_last = l[:, :, -1:]                                  # (B,nc,1,H)
+        # log-decay within chunk: l[t] = cumsum(dt * a)
+        l = jnp.cumsum(dts * a.reshape(g, r), axis=2)       # (B,nc,Q,G,R)
+        l_last = l[:, :, -1:]                               # (B,nc,1,G,R)
 
-    # ---- intra-chunk (quadratic within chunk) -----------------------------
-    scores = jnp.einsum("bctn,bcsn->bcts", cs, bs)         # (B,nc,Q,Q)
-    decay = jnp.exp(l[:, :, :, None, :] - l[:, :, None, :, :])  # (B,nc,Q,Q,H)
-    tri = jnp.tril(jnp.ones((chunk, chunk), bool))
-    m = scores[..., None] * decay * tri[None, None, :, :, None]
-    y_intra = jnp.einsum("bctsh,bcsh,bcshp->bcthp", m, dts, xs)
+        # ---- intra-chunk (quadratic within chunk) ---------------------------
+        scores = jnp.einsum("bctgn,bcsgn->bctsg", cs, bs)   # (B,nc,Q,Q,G)
+        tri = jnp.tril(jnp.ones((chunk, chunk), bool))[:, :, None, None]
+        # masked before the exp: above the diagonal l_t - l_s > 0 grows
+        # without bound over a chunk
+        seg = jnp.where(tri, l[:, :, :, None] - l[:, :, None], -jnp.inf)
+        m = scores[..., None] * jnp.exp(seg)                # (B,nc,Q,Q,G,R)
+        y_intra = jnp.einsum("bctsgr,bcsgr,bcsgrp->bctgrp", m, dts, xs)
 
-    # ---- chunk states ------------------------------------------------------
-    # state contribution of chunk c: sum_s exp(l_last - l_s) dt_s x_s (x) B_s
-    w = jnp.exp(l_last - l) * dts                          # (B,nc,Q,H)
-    chunk_state = jnp.einsum("bcsh,bcshp,bcsn->bchpn", w, xs, bs)
-    chunk_decay = jnp.exp(l_last[:, :, 0])                 # (B,nc,H)
+        # ---- chunk states ---------------------------------------------------
+        # state contribution of chunk c: sum_s exp(l_last - l_s) dt_s x_s (x) B_s
+        w = jnp.exp(l_last - l) * dts                       # (B,nc,Q,G,R)
+        chunk_state = jnp.einsum("bcsgr,bcsgrp,bcsgn->bcgrpn", w, xs, bs)
+        chunk_decay = jnp.exp(l_last[:, :, 0])              # (B,nc,G,R)
 
-    # ---- inter-chunk state scan -------------------------------------------
-    if h0 is None:
-        h0 = jnp.zeros((bsz, h, p, n), jnp.float32)
+        # ---- inter-chunk state scan -----------------------------------------
+        if h0 is None:
+            h0 = jnp.zeros((bsz, h, p, n), jnp.float32)
+        h0 = h0.reshape(bsz, g, r, p, n)
 
-    def step(carry, inp):
-        st, dcy = inp                                      # (B,H,P,N), (B,H)
-        new = carry * dcy[:, :, None, None] + st
-        return new, carry                                  # emit state BEFORE chunk
+        def step(carry, inp):
+            st, dcy = inp                                   # (B,G,R,P,N), (B,G,R)
+            new = carry * dcy[..., None, None] + st
+            return new, carry                               # state BEFORE chunk
 
-    states = jnp.moveaxis(chunk_state.astype(jnp.float32), 1, 0)
-    decays = jnp.moveaxis(chunk_decay, 1, 0)
-    h_final, h_prevs = maybe_scan(step, h0, (states, decays),
-                                  unroll=unroll)
-    h_prevs = jnp.moveaxis(h_prevs, 0, 1)                  # (B,nc,H,P,N)
+        states = jnp.moveaxis(chunk_state.astype(jnp.float32), 1, 0)
+        decays = jnp.moveaxis(chunk_decay, 1, 0)
+        h_final, h_prevs = maybe_scan(step, h0, (states, decays),
+                                      unroll=unroll)
+        h_prevs = jnp.moveaxis(h_prevs, 0, 1)               # (B,nc,G,R,P,N)
 
-    # ---- inter-chunk contribution to outputs ------------------------------
-    y_inter = jnp.einsum("bcth,bctn,bchpn->bcthp",
-                         jnp.exp(l), cs, h_prevs.astype(x.dtype))
-    y = (y_intra + y_inter).reshape(bsz, s, h, p)
-    return y, h_final
+        # ---- inter-chunk contribution to outputs ----------------------------
+        y_inter = jnp.einsum("bctgr,bctgn,bcgrpn->bctgrp",
+                             jnp.exp(l), cs, h_prevs)
+        y = (y_intra + y_inter).reshape(bsz, s, h, p)
+        return y, h_final.reshape(bsz, h, p, n)
 
 
-def mamba2_forward(params, x, *, d_state: int, headdim: int, expand: int,
-                   chunk: int = 128, unroll: bool = False):
+def mamba2_forward(params, x, *, d_state: int, headdim: int, n_groups: int,
+                   chunk: int, eps: float, unroll: bool = False):
     """Full-sequence Mamba2 mixer. x (B,S,d) -> (B,S,d)."""
-    out, _ = mamba2_forward_state(params, x, d_state=d_state, headdim=headdim,
-                                  expand=expand, chunk=chunk, unroll=unroll)
+    out, _ = mamba2_forward_state(params, x, d_state=d_state,
+                                  headdim=headdim, n_groups=n_groups,
+                                  chunk=chunk, eps=eps, unroll=unroll)
     return out
 
 
 def mamba2_forward_state(params, x, *, d_state: int, headdim: int,
-                         expand: int, chunk: int = 128,
+                         n_groups: int, chunk: int, eps: float,
                          unroll: bool = False):
     """Full-sequence Mamba2 that also returns the decode cache (final SSM
     state + conv window)."""
-    d_model = x.shape[-1]
-    d_inner = expand * d_model
-    n_heads = d_inner // headdim
-    proj = jnp.einsum("bsd,df->bsf", x, params["w_in"])
-    z, xbc_raw, dt = _split_proj(proj, d_inner, d_state, n_heads)
-    xbc = _causal_conv(xbc_raw, params["conv_w"])
-    xin = xbc[..., :d_inner]
-    b_in = xbc[..., d_inner:d_inner + d_state]
-    c_in = xbc[..., d_inner + d_state:]
-    dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])
-    a = -jnp.exp(params["a_log"])
-    bsz, s = x.shape[:2]
-    xh = xin.reshape(bsz, s, n_heads, headdim)
-    xh = shard_hint(xh, "batch", "seq", "tp", None)
-    y, h_final = ssd_chunked(xh, dt, a, b_in, c_in,
-                             chunk=min(chunk, s), unroll=unroll)
-    y = y + params["d_skip"][None, None, :, None] * xh.astype(y.dtype)
-    out = _gated_out(params, y.astype(x.dtype), z, d_model)
-    cache = {"h": h_final,                          # (B,H,P,N)
-             "conv": xbc_raw[:, -(params["conv_w"].shape[0] - 1):]}
-    return out, cache
+    with jax.named_scope("mamba2.mixer"):
+        n_heads = params["a_log"].shape[0]
+        d_inner = n_heads * headdim
+        proj = jnp.einsum("bsd,df->bsf", x, params["w_in"])
+        z, xbc_raw, dt = _split_proj(proj, d_inner, n_groups, d_state)
+        xbc = _causal_conv(xbc_raw, params["conv_w"], params["conv_b"])
+        xin, b_in, c_in = _split_xbc(xbc, d_inner, n_groups, d_state)
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])
+        a = -jnp.exp(params["a_log"])
+        bsz, s = x.shape[:2]
+        xh = xin.reshape(bsz, s, n_heads, headdim)
+        xh = shard_hint(xh, "batch", "seq", "tp", None)
+        y, h_final = ssd_chunked(xh, dt, a, b_in, c_in,
+                                 chunk=min(chunk, s), unroll=unroll)
+        y = y + params["d_skip"][None, None, :, None] * xh
+        out = _gated_out(params, y, z, n_groups, eps)
+        cache = {"h": h_final,                          # (B,H,P,N)
+                 "conv": xbc_raw[:, -(params["conv_w"].shape[0] - 1):]}
+        return out, cache
 
 
 # ---------------------------------------------------------------------------
 # decode
 # ---------------------------------------------------------------------------
 
-def init_mamba2_cache(batch: int, d_model: int, d_state: int, headdim: int,
-                      expand: int, conv_kernel: int, dtype=jnp.float32):
-    d_inner = expand * d_model
-    n_heads = d_inner // headdim
+def init_mamba2_cache(batch: int, d_state: int, headdim: int, n_heads: int,
+                      n_groups: int, conv_kernel: int, dtype=jnp.float32):
+    d_inner = n_heads * headdim
     return {
         "h": jnp.zeros((batch, n_heads, headdim, d_state), jnp.float32),
-        "conv": jnp.zeros((batch, conv_kernel - 1, d_inner + 2 * d_state),
-                          dtype),
+        "conv": jnp.zeros((batch, conv_kernel - 1,
+                           d_inner + 2 * n_groups * d_state), dtype),
     }
 
 
 def mamba2_decode(params, x, cache, *, d_state: int, headdim: int,
-                  expand: int):
+                  n_groups: int, eps: float):
     """One-token step. x (B,1,d)."""
-    d_model = x.shape[-1]
-    d_inner = expand * d_model
-    n_heads = d_inner // headdim
+    n_heads = params["a_log"].shape[0]
+    d_inner = n_heads * headdim
     proj = jnp.einsum("bsd,df->bsf", x, params["w_in"])
-    z, xbc, dt = _split_proj(proj, d_inner, d_state, n_heads)
+    z, xbc, dt = _split_proj(proj, d_inner, n_groups, d_state)
     # conv over the cached window + this token
     win = jnp.concatenate([cache["conv"], xbc], axis=1)    # (B, K, C)
-    conv_out = jnp.einsum("bkc,kc->bc", win, params["conv_w"])
-    conv_out = jax.nn.silu(conv_out.astype(jnp.float32)).astype(x.dtype)[:, None]
-    new_conv = win[:, 1:]
-    xin = conv_out[..., :d_inner]
-    b_in = conv_out[..., d_inner:d_inner + d_state]
-    c_in = conv_out[..., d_inner + d_state:]
+    conv_out = jnp.einsum("bkc,kc->bc", win.astype(jnp.float32),
+                          params["conv_w"].astype(jnp.float32))
+    conv_out = jax.nn.silu(conv_out + params["conv_b"].astype(jnp.float32))
+    xin, b_in, c_in = _split_xbc(conv_out, d_inner, n_groups, d_state)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])[:, 0]
     a = -jnp.exp(params["a_log"])
-    xh = xin[:, 0].reshape(-1, n_heads, headdim)
-    decay = jnp.exp(dt * a[None, :])                       # (B,H)
-    upd = jnp.einsum("bh,bhp,bn->bhpn", dt, xh.astype(jnp.float32),
-                     b_in[:, 0].astype(jnp.float32))
-    h_new = cache["h"] * decay[:, :, None, None] + upd
-    y = jnp.einsum("bhpn,bn->bhp", h_new, c_in[:, 0].astype(jnp.float32))
-    y = y + params["d_skip"][None, :, None] * xh.astype(jnp.float32)
-    y = y[:, None].astype(x.dtype)                         # (B,1,H,P)
-    out = _gated_out(params, y, z, d_model)
-    return out, {"h": h_new, "conv": new_conv}
+    bsz = x.shape[0]
+    r = n_heads // n_groups
+    xh = xin.reshape(bsz, n_groups, r, headdim)
+    decay = jnp.exp(dt * a[None, :]).reshape(bsz, n_groups, r)
+    upd = jnp.einsum("bgr,bgrp,bgn->bgrpn", dt.reshape(bsz, n_groups, r), xh,
+                     b_in)
+    h = cache["h"].reshape(bsz, n_groups, r, headdim, d_state)
+    h_new = h * decay[..., None, None] + upd
+    y = jnp.einsum("bgrpn,bgn->bgrp", h_new, c_in).reshape(
+        bsz, n_heads, headdim)
+    y = y + params["d_skip"][None, :, None] * xh.reshape(bsz, n_heads, headdim)
+    out = _gated_out(params, y[:, None], z, n_groups, eps)
+    return out, {"h": h_new.reshape(cache["h"].shape), "conv": win[:, 1:]}

@@ -1,8 +1,20 @@
 """Transformer assembly: segments of scanned layer patterns, with train,
 prefill, and decode paths. See configs/base.py for the segment machinery.
+
+Zamba2's hybrid layers (``LayerSpec.shared_block >= 0``) invoke one of the
+shared transformer blocks on the stream concatenated with
+the original embeddings ``e``, and add that block's output, mapped by the
+layer's own ``linear``, to the layer's mixer input only:
+
+    h' = h + Mamba2(RMSNorm(h + linear(block(h, e))))
+    block(h, e) = MLP_adapted(RMSNorm(Attn(RMSNorm([h, e]))))
+
+with no residual inside the block. The MLP is gated GELU; its gate and up
+projections carry the layer's own low-rank adapter.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any
 
@@ -45,8 +57,10 @@ class Transformer:
 
     def __init__(self, cfg: ArchConfig):
         self.cfg = cfg
-        self.has_shared = any(ls.mixer == "shared_attn"
-                              for ls in cfg.layer_specs())
+        self.has_shared = cfg.n_shared_blocks > 0
+        # Zamba2's attention scale: its head dim is twice the per-head width
+        # of the stream it attends over
+        self._shared_scale = (cfg.resolved_head_dim / 2) ** -0.5
 
     # ------------------------------------------------------------------
     # init
@@ -67,27 +81,27 @@ class Transformer:
                 cfg.qkv_bias, dt)
         elif spec.mixer == "mamba2":
             params["mixer"], axes["mixer"] = ssm_mod.init_mamba2(
-                kmix, d, cfg.ssm_state, cfg.ssm_headdim, cfg.ssm_expand,
-                cfg.conv_kernel, dt)
+                kmix, d, cfg.ssm_state, cfg.ssm_headdim,
+                cfg.ssm_heads, cfg.ssm_ngroups, cfg.conv_kernel, dt)
         elif spec.mixer == "rwkv6":
             params["mixer"], axes["mixer"] = rwkv_mod.init_rwkv6_timemix(
                 kmix, d, cfg.rwkv_headdim, max(4, cfg.lora_rank or 32), dt)
-        elif spec.mixer == "shared_attn":
-            r = max(1, cfg.lora_rank)
-            hd = cfg.resolved_head_dim
-            ks = jax.random.split(kmix, 4)
-            params["mixer"] = {
-                "lora_q_a": _dense_init(ks[0], (d, r), 0, dt),
-                "lora_q_b": jnp.zeros((r, cfg.n_heads * hd), dt),
-                "lora_o_a": _dense_init(ks[1], (cfg.n_heads * hd, r), 0, dt),
-                "lora_o_b": jnp.zeros((r, d), dt),
-            }
-            axes["mixer"] = {
-                "lora_q_a": ("fsdp", None), "lora_q_b": (None, "tp"),
-                "lora_o_a": ("tp", None), "lora_o_b": (None, "fsdp"),
-            }
         else:
             raise ValueError(f"unknown mixer {spec.mixer}")
+
+        if spec.shared_block >= 0:
+            r = cfg.adapter_rank
+            ks = jax.random.split(jax.random.fold_in(kmix, 1), 2)
+            params["hybrid"] = {
+                "adapter_a": _dense_init(ks[0], (d, r), 0, dt),
+                "adapter_gate": jnp.zeros((r, cfg.d_ff), dt),
+                "adapter_up": jnp.zeros((r, cfg.d_ff), dt),
+                "linear": _dense_init(ks[1], (d, d), 0, dt),
+            }
+            axes["hybrid"] = {
+                "adapter_a": ("fsdp", None), "adapter_gate": (None, "tp"),
+                "adapter_up": (None, "tp"), "linear": ("fsdp", None),
+            }
 
         if spec.ffn != "none":
             params["norm2"], axes["norm2"] = init_rmsnorm(d, dt)
@@ -100,10 +114,27 @@ class Transformer:
         elif spec.ffn == "rwkv_cm":
             params["ffn"], axes["ffn"] = rwkv_mod.init_rwkv6_channelmix(
                 kffn, d, cfg.d_ff, dt)
-        elif spec.ffn in ("none", "shared_mlp"):
+        elif spec.ffn == "none":
             params["ffn"], axes["ffn"] = {}, {}
         else:
             raise ValueError(f"unknown ffn {spec.ffn}")
+        return params, axes
+
+    def _init_shared_block(self, key):
+        """One shared transformer block: attention from the 2 d_model wide
+        concatenation back to d_model, and the gated MLP."""
+        cfg = self.cfg
+        dt = _dtype(cfg)
+        d = cfg.d_model
+        ka, km = jax.random.split(key)
+        params: dict[str, Any] = {}
+        axes: dict[str, Any] = {}
+        params["norm_in"], axes["norm_in"] = init_rmsnorm(2 * d, dt)
+        params["attn"], axes["attn"] = attn.init_attention(
+            ka, 2 * d, cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim,
+            cfg.qkv_bias, dt, d_out=d)
+        params["norm_ff"], axes["norm_ff"] = init_rmsnorm(d, dt)
+        params["mlp"], axes["mlp"] = init_mlp(km, d, cfg.d_ff, dt)
         return params, axes
 
     def _layer_axes(self, spec: LayerSpec):
@@ -145,13 +176,11 @@ class Transformer:
         params["segments"] = seg_params
 
         if self.has_shared:
-            ks = jax.random.split(keys[1], 2)
-            sh_attn, sh_attn_ax = attn.init_attention(
-                ks[0], cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
-                cfg.resolved_head_dim, cfg.qkv_bias, dt)
-            sh_mlp, sh_mlp_ax = init_mlp(ks[1], cfg.d_model, cfg.d_ff, dt)
-            params["shared"] = {"attn": sh_attn, "mlp": sh_mlp}
-            self._shared_axes = {"attn": sh_attn_ax, "mlp": sh_mlp_ax}
+            params["shared"], self._shared_axes = {}, {}
+            for k, kb in enumerate(jax.random.split(keys[1],
+                                                    cfg.n_shared_blocks)):
+                params["shared"][str(k)], self._shared_axes[str(k)] = (
+                    self._init_shared_block(kb))
 
         self._axes = {
             "embed": self._embed_axes,
@@ -178,16 +207,31 @@ class Transformer:
     # layer application (full sequence)
     # ------------------------------------------------------------------
 
-    def _merged_shared_attn(self, lora, shared):
+    def _shared_block(self, spec: LayerSpec, lparams, shared, x, attend):
+        """``linear(block(x, e))`` of a hybrid layer, and what ``attend``
+        returned beside the attention's output. ``shared`` is (the shared
+        blocks' params, the original embeddings e); ``attend(p, h)`` runs
+        the block's attention on the normed concatenation (full sequence,
+        prefill or decode) and returns (out, cache)."""
         cfg = self.cfg
-        hd = cfg.resolved_head_dim
-        d = cfg.d_model
-        dq = (lora["lora_q_a"] @ lora["lora_q_b"]).reshape(d, cfg.n_heads, hd)
-        do = (lora["lora_o_a"] @ lora["lora_o_b"]).reshape(cfg.n_heads, hd, d)
-        p = dict(shared["attn"])
-        p["wq"] = p["wq"] + dq
-        p["wo"] = p["wo"] + do
-        return p
+        blocks, emb = shared
+        bp = blocks[str(spec.shared_block)]
+        hp = lparams["hybrid"]
+        with jax.named_scope("zamba2.shared_block"):
+            h = rmsnorm(bp["norm_in"], jnp.concatenate([x, emb], axis=-1),
+                        cfg.norm_eps)
+            a, kv = attend(bp["attn"], h)
+            h = rmsnorm(bp["norm_ff"], a, cfg.norm_eps)
+            mp = bp["mlp"]
+            low = jnp.einsum("...d,dr->...r", h, hp["adapter_a"])
+            gate = (jnp.einsum("...d,df->...f", h, mp["w_gate"])
+                    + jnp.einsum("...r,rf->...f", low, hp["adapter_gate"]))
+            up = (jnp.einsum("...d,df->...f", h, mp["w_up"])
+                  + jnp.einsum("...r,rf->...f", low, hp["adapter_up"]))
+            u = jax.nn.gelu(gate.astype(jnp.float32), approximate=False)
+            u = u.astype(up.dtype) * up
+            out = jnp.einsum("...f,fd->...d", u, mp["w_down"])
+            return jnp.einsum("...d,de->...e", out, hp["linear"]), kv
 
     def _apply_mixer(self, spec: LayerSpec, lparams, shared, h, positions):
         cfg = self.cfg
@@ -197,18 +241,12 @@ class Transformer:
                 window=cfg.window, chunk=cfg.chunk, use_rope=spec.use_rope,
                 rope_theta=cfg.rope_theta, block_q=cfg.block_q,
                 causal_buckets=cfg.causal_buckets, unroll=cfg.scan_unroll)
-        if spec.mixer == "shared_attn":
-            p = self._merged_shared_attn(lparams["mixer"], shared)
-            return attn.attention_forward(
-                p, h, positions, kind=spec.attn_kind, window=cfg.window,
-                chunk=cfg.chunk, use_rope=spec.use_rope,
-                rope_theta=cfg.rope_theta, block_q=cfg.block_q,
-                causal_buckets=cfg.causal_buckets, unroll=cfg.scan_unroll)
         if spec.mixer == "mamba2":
             return ssm_mod.mamba2_forward(
                 lparams["mixer"], h, d_state=cfg.ssm_state,
-                headdim=cfg.ssm_headdim, expand=cfg.ssm_expand,
-                chunk=cfg.ssd_chunk, unroll=cfg.scan_unroll)
+                headdim=cfg.ssm_headdim, n_groups=cfg.ssm_ngroups,
+                chunk=cfg.ssd_chunk, eps=cfg.norm_eps,
+                unroll=cfg.scan_unroll)
         if spec.mixer == "rwkv6":
             return rwkv_mod.rwkv6_timemix_forward(lparams["mixer"], h,
                                                   cfg.rwkv_headdim,
@@ -227,16 +265,27 @@ class Transformer:
                 iterative_topk=cfg.scan_unroll)
         if spec.ffn == "rwkv_cm":
             return rwkv_mod.rwkv6_channelmix_forward(lparams["ffn"], h), 0.0
-        if spec.ffn == "shared_mlp":
-            return mlp(shared["mlp"], h), 0.0
         return None, 0.0
+
+    def _mixer_input(self, spec: LayerSpec, lparams, shared, x, positions):
+        """The mixer's normed input: of x, or of x plus the shared block's
+        contribution in a hybrid layer."""
+        cfg = self.cfg
+        if spec.shared_block >= 0:
+            out, _ = self._shared_block(
+                spec, lparams, shared, x, lambda p, h: (attn.attention_forward(
+                    p, h, positions, rope_theta=cfg.rope_theta,
+                    block_q=cfg.block_q, causal_buckets=cfg.causal_buckets,
+                    unroll=cfg.scan_unroll, scale=self._shared_scale), None))
+            x = x + out
+        return rmsnorm(lparams["norm1"], x, cfg.norm_eps)
 
     def _apply_layer(self, spec: LayerSpec, lparams, shared, x, positions):
         aux = jnp.zeros((), jnp.float32)
-        h = rmsnorm(lparams["norm1"], x)
+        h = self._mixer_input(spec, lparams, shared, x, positions)
         x = x + self._apply_mixer(spec, lparams, shared, h, positions)
         if spec.ffn != "none":
-            h2 = rmsnorm(lparams["norm2"], x)
+            h2 = rmsnorm(lparams["norm2"], x, self.cfg.norm_eps)
             out, a = self._apply_ffn(spec, lparams, shared, h2)
             x = x + out
             aux = aux + a
@@ -262,31 +311,8 @@ class Transformer:
     def forward(self, params, tokens, prefix=None, remat=None):
         """tokens (B, S) -> (logits (B, S, V), aux). prefix (B, P, d) stub
         embeddings are prepended (vlm / audio) and stripped from logits."""
-        cfg = self.cfg
-        remat = cfg.remat if remat is None else remat
-        x = self._embed_tokens(params, tokens, prefix)
-        positions = jnp.arange(x.shape[1])
-        shared = params.get("shared")
-        aux = jnp.zeros((), jnp.float32)
-
-        for seg_params, seg in zip(params["segments"], cfg.segments):
-            def step(carry, p_step, seg=seg):
-                x, aux = carry
-                for j, ls in enumerate(seg.pattern):
-                    x, a = self._apply_layer(ls, p_step[str(j)], shared, x,
-                                             positions)
-                    aux = aux + a
-                return (x, aux), None
-
-            step_fn = jax.checkpoint(step) if remat else step
-            (x, aux), _ = maybe_scan(step_fn, (x, aux), seg_params,
-                                     unroll=cfg.scan_unroll)
-
-        x = rmsnorm(params["final_norm"], x)
-        if prefix is not None:
-            x = x[:, prefix.shape[1]:]
-        logits = unembed(params["embed"], x)
-        return logits, aux
+        x, aux = self._hidden_states(params, tokens, prefix, remat)
+        return unembed(params["embed"], x), aux
 
     def loss_fn(self, params, batch):
         """batch: {"tokens": (B,S), "labels": (B,S), ["prefix": (B,P,d)]}"""
@@ -318,24 +344,38 @@ class Transformer:
                               (xs, ls), unroll=cfg.scan_unroll)
         return total / s + AUX_WEIGHT * aux
 
-    def _hidden_states(self, params, tokens, prefix):
+    def _shared_inputs(self, params, x):
+        """What a hybrid layer reads besides its own params: the shared
+        blocks and the original embeddings."""
+        return (params["shared"], x) if self.has_shared else None
+
+    def _hidden_states(self, params, tokens, prefix, remat=None):
+        """Final-normed hidden states. Under remat each layer is its own
+        checkpoint, so the backward pass recomputes one layer at a time."""
         cfg = self.cfg
+        remat = cfg.remat if remat is None else remat
         x = self._embed_tokens(params, tokens, prefix)
         positions = jnp.arange(x.shape[1])
-        shared = params.get("shared")
+        shared = self._shared_inputs(params, x)
         aux = jnp.zeros((), jnp.float32)
+
+        def layer(carry, p, ls):
+            x, aux = carry
+            x, a = self._apply_layer(ls, p, shared, x, positions)
+            return x, aux + a
+
         for seg_params, seg in zip(params["segments"], cfg.segments):
-            def step(carry, p_step, seg=seg):
-                x, aux = carry
-                for j, ls in enumerate(seg.pattern):
-                    x, a = self._apply_layer(ls, p_step[str(j)], shared, x,
-                                             positions)
-                    aux = aux + a
-                return (x, aux), None
-            step_fn = jax.checkpoint(step) if cfg.remat else step
-            (x, aux), _ = maybe_scan(step_fn, (x, aux), seg_params,
+            layers = [functools.partial(layer, ls=ls) for ls in seg.pattern]
+            if remat:
+                layers = [jax.checkpoint(f) for f in layers]
+
+            def step(carry, p_step, layers=layers):
+                for j, f in enumerate(layers):
+                    carry = f(carry, p_step[str(j)])
+                return carry, None
+            (x, aux), _ = maybe_scan(step, (x, aux), seg_params,
                                      unroll=cfg.scan_unroll)
-        x = rmsnorm(params["final_norm"], x)
+        x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
         if prefix is not None:
             x = x[:, prefix.shape[1]:]
         return x, aux
@@ -349,7 +389,7 @@ class Transformer:
         cfg = self.cfg
         dt = _dtype(cfg)
         cache: dict[str, Any] = {}
-        if spec.mixer in ("attn", "shared_attn"):
+        if spec.mixer == "attn":
             # natural: full-length position-ordered cache even for swa /
             # chunk layers (no ring truncation) — the layout the paged
             # serving pool ingests; visibility is enforced by masks
@@ -359,8 +399,8 @@ class Transformer:
                 cfg.chunk, dt)
         elif spec.mixer == "mamba2":
             cache["mixer"] = ssm_mod.init_mamba2_cache(
-                batch, cfg.d_model, cfg.ssm_state, cfg.ssm_headdim,
-                cfg.ssm_expand, cfg.conv_kernel, dt)
+                batch, cfg.ssm_state, cfg.ssm_headdim, cfg.ssm_heads,
+                cfg.ssm_ngroups, cfg.conv_kernel, dt)
         elif spec.mixer == "rwkv6":
             n_heads = cfg.d_model // cfg.rwkv_headdim
             cache["mixer"] = {
@@ -368,6 +408,11 @@ class Transformer:
                                   cfg.rwkv_headdim), jnp.float32),
                 "tm_last": jnp.zeros((batch, 1, cfg.d_model), dt),
             }
+        if spec.shared_block >= 0:
+            # the shared block's attention: full causal, over every position
+            cache["shared"] = attn.init_kv_cache(
+                batch, "full", max_len, cfg.n_kv_heads, cfg.resolved_head_dim,
+                dtype=dt)
         if spec.ffn == "rwkv_cm":
             cache["ffn"] = {"cm_last": jnp.zeros((batch, 1, cfg.d_model), dt)}
         else:
@@ -401,10 +446,13 @@ class Transformer:
             pat = {}
             for j, ls in enumerate(seg.pattern):
                 one = self._layer_cache_shape(ls, n_slots, 1)
-                if ls.mixer in ("attn", "shared_attn"):
-                    one["mixer"] = attn.init_paged_kv_cache(
-                        n_blocks, block_size, cfg.n_kv_heads,
-                        cfg.resolved_head_dim, _dtype(cfg))
+                pool = functools.partial(
+                    attn.init_paged_kv_cache, n_blocks, block_size,
+                    cfg.n_kv_heads, cfg.resolved_head_dim, _dtype(cfg))
+                if ls.mixer == "attn":
+                    one["mixer"] = pool()
+                if ls.shared_block >= 0:
+                    one["shared"] = pool()
                 pat[str(j)] = jax.tree.map(
                     lambda x: jnp.zeros((seg.n_steps,) + x.shape, x.dtype),
                     one)
@@ -421,7 +469,7 @@ class Transformer:
             pat = {}
             for j, ls in enumerate(seg.pattern):
                 c: dict[str, Any] = {}
-                if ls.mixer in ("attn", "shared_attn"):
+                if ls.mixer == "attn":
                     c["mixer"] = attn_axes(ls.attn_kind)
                 elif ls.mixer == "mamba2":
                     c["mixer"] = {"h": (None, "batch", "tp", None, None),
@@ -429,35 +477,47 @@ class Transformer:
                 elif ls.mixer == "rwkv6":
                     c["mixer"] = {"wkv": (None, "batch", "tp", None, None),
                                   "tm_last": (None, "batch", None, None)}
+                if ls.shared_block >= 0:
+                    c["shared"] = attn_axes("full")
                 c["ffn"] = ({"cm_last": (None, "batch", None, None)}
                             if ls.ffn == "rwkv_cm" else {})
                 pat[str(j)] = c
             axes.append(pat)
         return axes
 
+    def _decode_attention(self, p, h, kv_cache, pos, table, kind, use_rope,
+                          scale=None):
+        """One-token attention over the dense cache, or over the paged pool
+        when ``table`` is given."""
+        cfg = self.cfg
+        kw = dict(kind=kind, window=cfg.window, chunk=cfg.chunk,
+                  use_rope=use_rope, rope_theta=cfg.rope_theta, scale=scale)
+        if table is None:
+            return attn.decode_attention(p, h, kv_cache, pos, **kw)
+        return attn.paged_decode_attention(p, h, kv_cache, table, pos, **kw)
+
     def _decode_layer(self, spec: LayerSpec, lparams, shared, cache, x, pos,
                       table=None):
         cfg = self.cfg
-        h = rmsnorm(lparams["norm1"], x)
         new_cache = dict(cache)
-        if spec.mixer in ("attn", "shared_attn"):
-            p = (self._merged_shared_attn(lparams["mixer"], shared)
-                 if spec.mixer == "shared_attn" else lparams["mixer"])
-            if table is None:
-                out, kv = attn.decode_attention(
-                    p, h, cache["mixer"], pos, kind=spec.attn_kind,
-                    window=cfg.window, chunk=cfg.chunk,
-                    use_rope=spec.use_rope, rope_theta=cfg.rope_theta)
-            else:
-                out, kv = attn.paged_decode_attention(
-                    p, h, cache["mixer"], table, pos, kind=spec.attn_kind,
-                    window=cfg.window, chunk=cfg.chunk,
-                    use_rope=spec.use_rope, rope_theta=cfg.rope_theta)
-            new_cache["mixer"] = kv
+        if spec.shared_block >= 0:
+            out, new_cache["shared"] = self._shared_block(
+                spec, lparams, shared, x,
+                lambda p, h: self._decode_attention(
+                    p, h, cache["shared"], pos, table, "full", True,
+                    self._shared_scale))
+            h = rmsnorm(lparams["norm1"], x + out, cfg.norm_eps)
+        else:
+            h = rmsnorm(lparams["norm1"], x, cfg.norm_eps)
+        if spec.mixer == "attn":
+            out, new_cache["mixer"] = self._decode_attention(
+                lparams["mixer"], h, cache["mixer"], pos, table,
+                spec.attn_kind, spec.use_rope)
         elif spec.mixer == "mamba2":
             out, mc = ssm_mod.mamba2_decode(
                 lparams["mixer"], h, cache["mixer"], d_state=cfg.ssm_state,
-                headdim=cfg.ssm_headdim, expand=cfg.ssm_expand)
+                headdim=cfg.ssm_headdim, n_groups=cfg.ssm_ngroups,
+                eps=cfg.norm_eps)
             new_cache["mixer"] = mc
         elif spec.mixer == "rwkv6":
             out, rc = rwkv_mod.rwkv6_timemix_decode(
@@ -469,7 +529,7 @@ class Transformer:
         x = x + out
 
         if spec.ffn != "none":
-            h2 = rmsnorm(lparams["norm2"], x)
+            h2 = rmsnorm(lparams["norm2"], x, cfg.norm_eps)
             if spec.ffn == "rwkv_cm":
                 out2, fc = rwkv_mod.rwkv6_channelmix_decode(
                     lparams["ffn"], h2, cache["ffn"])
@@ -491,7 +551,7 @@ class Transformer:
         x = embed(params["embed"], tokens[:, None], cfg.embed_impl)
         if cfg.embed_scale:
             x = x * math.sqrt(cfg.d_model)
-        shared = params.get("shared")
+        shared = self._shared_inputs(params, x)
         new_caches = []
         for seg_params, seg_cache, seg in zip(params["segments"], caches,
                                               cfg.segments):
@@ -506,7 +566,7 @@ class Transformer:
 
             x, new_seg_cache = jax.lax.scan(step, x, (seg_params, seg_cache))
             new_caches.append(new_seg_cache)
-        x = rmsnorm(params["final_norm"], x)
+        x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
         logits = unembed(params["embed"], x)[:, 0]
         return logits, new_caches
 
@@ -519,7 +579,7 @@ class Transformer:
         b, s_total = x.shape[:2]
         max_len = max_len or s_total
         positions = jnp.arange(s_total)
-        shared = params.get("shared")
+        shared = self._shared_inputs(params, x)
         caches = self.init_cache(b, max_len, natural)
         new_caches = []
         for seg_params, seg_cache, seg in zip(params["segments"], caches,
@@ -535,7 +595,7 @@ class Transformer:
 
             x, new_seg_cache = jax.lax.scan(step, x, (seg_params, seg_cache))
             new_caches.append(new_seg_cache)
-        x = rmsnorm(params["final_norm"], x)
+        x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
         return x, new_caches, s_total
 
     def prefill(self, params, tokens, prefix=None, max_len=None):
@@ -600,13 +660,12 @@ class Transformer:
             for j, ls in enumerate(seg.pattern):
                 cp, cg = seg_pre[str(j)], seg_paged[str(j)]
                 new = {}
-                if ls.mixer in ("attn", "shared_attn"):
-                    new["mixer"] = {
-                        "k": scatter_blocks(cg["mixer"]["k"],
-                                            cp["mixer"]["k"]),
-                        "v": scatter_blocks(cg["mixer"]["v"],
-                                            cp["mixer"]["v"]),
-                    }
+                if ls.shared_block >= 0:
+                    new["shared"] = jax.tree.map(scatter_blocks,
+                                                 cg["shared"], cp["shared"])
+                if ls.mixer == "attn":
+                    new["mixer"] = jax.tree.map(scatter_blocks,
+                                                cg["mixer"], cp["mixer"])
                 else:
                     new["mixer"] = jax.tree.map(
                         lambda g, p: g.at[:, slots].set(p.astype(g.dtype)),
@@ -621,22 +680,31 @@ class Transformer:
     def _prefill_layer(self, spec: LayerSpec, lparams, shared, cache, x,
                        positions):
         cfg = self.cfg
-        h = rmsnorm(lparams["norm1"], x)
         new_cache = dict(cache)
-        if spec.mixer in ("attn", "shared_attn"):
-            p = (self._merged_shared_attn(lparams["mixer"], shared)
-                 if spec.mixer == "shared_attn" else lparams["mixer"])
+        if spec.shared_block >= 0:
+            def attend(p, h):
+                out, (k, v) = attn.attention_forward_kv(
+                    p, h, positions, rope_theta=cfg.rope_theta,
+                    block_q=cfg.block_q, scale=self._shared_scale)
+                return out, attn.fill_kv_cache(cache["shared"], k, v, "full")
+
+            out, new_cache["shared"] = self._shared_block(
+                spec, lparams, shared, x, attend)
+            h = rmsnorm(lparams["norm1"], x + out, cfg.norm_eps)
+        else:
+            h = rmsnorm(lparams["norm1"], x, cfg.norm_eps)
+        if spec.mixer == "attn":
             out, (k, v) = attn.attention_forward_kv(
-                p, h, positions, kind=spec.attn_kind, window=cfg.window,
-                chunk=cfg.chunk, use_rope=spec.use_rope,
+                lparams["mixer"], h, positions, kind=spec.attn_kind,
+                window=cfg.window, chunk=cfg.chunk, use_rope=spec.use_rope,
                 rope_theta=cfg.rope_theta, block_q=cfg.block_q)
             new_cache["mixer"] = attn.fill_kv_cache(
                 cache["mixer"], k, v, spec.attn_kind, cfg.window, cfg.chunk)
         elif spec.mixer == "mamba2":
             out, st = ssm_mod.mamba2_forward_state(
                 lparams["mixer"], h, d_state=cfg.ssm_state,
-                headdim=cfg.ssm_headdim, expand=cfg.ssm_expand,
-                chunk=cfg.ssd_chunk)
+                headdim=cfg.ssm_headdim, n_groups=cfg.ssm_ngroups,
+                chunk=cfg.ssd_chunk, eps=cfg.norm_eps)
             new_cache["mixer"] = st
         elif spec.mixer == "rwkv6":
             out, st = rwkv_mod.rwkv6_timemix_forward_state(
@@ -646,7 +714,7 @@ class Transformer:
             raise ValueError(spec.mixer)
         x = x + out
         if spec.ffn != "none":
-            h2 = rmsnorm(lparams["norm2"], x)
+            h2 = rmsnorm(lparams["norm2"], x, cfg.norm_eps)
             if spec.ffn == "rwkv_cm":
                 out2 = rwkv_mod.rwkv6_channelmix_forward(lparams["ffn"], h2)
                 new_cache["ffn"] = {"cm_last": h2[:, -1:]}

@@ -58,7 +58,7 @@ def model_pads_ok(model) -> bool:
     """True when every layer is pure attention (no recurrent mixer, no
     rwkv channel-mix ffn) — the archs for which right-padded bucketed
     prefill is exact."""
-    return all(ls.mixer in ("attn", "shared_attn") and ls.ffn != "rwkv_cm"
+    return all(ls.mixer == "attn" and ls.ffn != "rwkv_cm"
                for seg in model.cfg.segments for ls in seg.pattern)
 
 

@@ -411,7 +411,9 @@ def test_ssd_chunked_model_matches_sequential_ref():
     a = -jnp.exp(jax.random.normal(ks[2], (h,)) * 0.3)
     b_in = jax.random.normal(ks[3], (b, s, n))
     c_in = jax.random.normal(ks[4], (b, s, n))
-    got_y, got_s = ssd_chunked(x, dt, a, b_in, c_in, chunk=8)
+    # one B/C group: (B, S, 1, N) to the model, (B, S, N) to the oracle
+    got_y, got_s = ssd_chunked(x, dt, a, b_in[:, :, None], c_in[:, :, None],
+                               chunk=8)
     want_y, want_s = ref.mamba2_ssd_ref(x, dt, a, b_in, c_in)
     np.testing.assert_allclose(np.asarray(got_y), np.asarray(want_y),
                                rtol=1e-3, atol=1e-4)

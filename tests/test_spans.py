@@ -12,7 +12,7 @@ import pytest
 from repro.api import FederationSpec, init_state, train
 from repro.api.engines import get_engine, resolve_engine
 from repro.api.state import round_batch, sigmas_for
-from repro.configs import smoke_variant
+from repro.configs import get_arch, smoke_variant
 from repro.configs.rwkv6_1_6b import chip_share
 from repro.models.linear import init_linear, logreg_loss
 from repro.models.transformer import Transformer
@@ -49,14 +49,16 @@ def no_persistent_cache():
     cc.reset_cache()
 
 
-def _rwkv_round():
-    """A tiny RWKV-6 DP federation's round function and its operands."""
-    cfg = smoke_variant(chip_share(1))
+def _rwkv_round(cfg=None, engine="map", n_clients=C):
+    """A tiny DP federation's round function and its operands (RWKV-6
+    unless ``cfg`` names another model)."""
+    cfg = cfg or smoke_variant(chip_share(1))
     model = Transformer(cfg)
     spec = FederationSpec(
-        n_clients=C, tau=TAU, loss_fn=model.loss_fn, optimizer=sgd(0.01),
-        engine="map", dp=True, clip_norm=1.0, num_microbatches=1,
-        kernel_backend="auto", sigmas=(0.1,) * C, batch_sizes=(B,) * C)
+        n_clients=n_clients, tau=TAU, loss_fn=model.loss_fn,
+        optimizer=sgd(0.01), engine=engine, dp=True, clip_norm=1.0,
+        num_microbatches=1, kernel_backend="auto", sigmas=(0.1,) * n_clients,
+        batch_sizes=(B,) * n_clients)
     state = init_state(spec, model.init(jax.random.PRNGKey(0)),
                        key=jax.random.PRNGKey(1))
 
@@ -89,6 +91,32 @@ def test_round_scopes_only_change_metadata(no_persistent_cache, monkeypatch):
     plain = _compiled_text(spec, args)
     assert not any(scope in plain for scope in SCOPES)
     assert canonical_hlo(scoped) == canonical_hlo(plain)
+
+
+def _op_names(spec, args) -> list[str]:
+    return re.findall(r'op_name="([^"]*)"', _compiled_text(spec, args))
+
+
+@pytest.mark.parametrize("arch, engine, scopes", [
+    ("zamba2-7b", "map", ("mamba2.mixer", "mamba2.ssd_scan",
+                          "zamba2.shared_block", "dppasgd.average")),
+    ("rwkv6-1.6b", "shard_map", ("dppasgd.average",)),
+])
+def test_round_carries_the_model_and_average_scopes(no_persistent_cache,
+                                                    arch, engine, scopes):
+    """Zamba2's mixer, SSD scan and shared block, and the Eq. 7b average
+    (with its pmean under shard_map), in the compiled round's op names."""
+    cfg = smoke_variant(get_arch(arch) if arch != "rwkv6-1.6b"
+                        else chip_share(1))
+    spec, args = _rwkv_round(cfg, engine, 1 if engine == "shard_map" else C)
+    op_names = _op_names(spec, args)
+    for scope in scopes:
+        assert any(scope in name.split("/") for name in op_names), scope
+    if "mamba2.ssd_scan" in scopes:
+        # nested in the mixer, and in the backward pass too
+        assert any("mamba2.mixer/mamba2.ssd_scan" in n for n in op_names)
+        assert any("transpose(" in n and "zamba2.shared_block" in n
+                   for n in op_names)
 
 
 def test_canonical_hlo_keeps_the_program():
