@@ -67,7 +67,6 @@ class ArchConfig:
     ssd_chunk: int = 128
     # --- RWKV ---
     rwkv_headdim: int = 64
-    rwkv_chunk: int = 0        # 0 = per-token scan; >0 = chunk-parallel WKV6
     lora_rank: int = 0         # RWKV-6 time-decay LoRA rank
     # --- shared transformer blocks (zamba2) ---
     adapter_rank: int = 0      # per-invocation adapter on the MLP's gate/up

@@ -322,8 +322,8 @@ def print_mesh_report(rows) -> None:
 # run + report
 # ---------------------------------------------------------------------------
 
-OPTS = ("scan_accum", "onehot_embed", "causal_buckets", "rwkv_chunk",
-        "moe_dense", "donate_cache", "gather_weights", "ddp")
+OPTS = ("scan_accum", "onehot_embed", "causal_buckets", "moe_dense",
+        "donate_cache", "gather_weights", "ddp")
 
 
 def apply_opts(cfg, opts: tuple[str, ...]):
@@ -334,8 +334,6 @@ def apply_opts(cfg, opts: tuple[str, ...]):
         kw["embed_impl"] = "one_hot"
     if "causal_buckets" in opts:
         kw["causal_buckets"] = True
-    if "rwkv_chunk" in opts:
-        kw["rwkv_chunk"] = 64
     if "moe_dense" in opts:
         kw["moe_impl"] = "dense"
     return replace(cfg, **kw) if kw else cfg

@@ -7,20 +7,31 @@ with w_t = exp(-exp(w0 + lora(x~_t))) data-dependent per channel.
 
 Channel-mix: squared-ReLU MLP with token shift.
 
-Training/prefill uses a lax.scan over the sequence (baseline; the Pallas
-kernel in repro/kernels/rwkv6_scan.py is the TPU hot path); decode is the
-one-step recurrence.
+Training and prefill (any S) compute the WKV with the exact
+chunk-parallel ``wkv6_chunked``, whose contractions are matmuls; decode
+is the one-step recurrence ``wkv6_scan``, which is also the per-token
+oracle of the tests. The Pallas kernel in
+repro/kernels/rwkv6_scan.py is not on the model's path.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models.layers import _dense_init
-from repro.models.scan_utils import maybe_map, maybe_scan
+from repro.models.scan_utils import maybe_scan
 from repro.models.sharding import shard_hint
 
 WKV_SCAN_SCOPE = "rwkv6.wkv_scan"
+WKV_CHUNK = 64          # tokens a chunk of wkv6_chunked's state pass
+WKV_SUBCHUNK = 16       # tokens a sub-chunk of its in-chunk form
+HIGHEST = jax.lax.Precision.HIGHEST
+# f32's exp() is 0 below this, so a product across a token whose log-decay
+# is lower reads 0 either way; the floor keeps the in-chunk sums short
+LOG_DECAY_FLOOR = -110.0
 
 
 def init_rwkv6_timemix(key, d_model: int, headdim: int = 64, lora_rank: int = 32,
@@ -98,82 +109,116 @@ def wkv6_scan(r, k, v, w, u, s0=None, unroll: bool = False):
     return jnp.moveaxis(ys, 0, 1), s_final
 
 
-def wkv6_chunked(r, k, v, log_decay, u, s0=None, chunk: int = 64,
-                 unroll: bool = False):
-    """Chunk-parallel WKV6 (fla-style): intra-chunk quadratic form + one
-    state read/write per chunk instead of per token. Exact (all exponents
-    are <= 0 under the causal mask, so no overflow).
+def _wkv_chunk(s: int) -> int:
+    """Chunk length for a sequence of ``s`` tokens: WKV_CHUNK, or ``s``
+    rounded up to a whole number of sub-chunks when it is shorter."""
+    if s >= WKV_CHUNK:
+        return WKV_CHUNK
+    return -(-s // WKV_SUBCHUNK) * WKV_SUBCHUNK
 
-    r/k/v/log_decay (B, S, H, hd); u (H, hd). Returns (y, final state)."""
+
+def wkv6_chunked(r, k, v, log_decay, u, s0=None, unroll: bool = False):
+    """Chunk-parallel WKV6, exact, with its contractions as matmuls.
+
+    r/k/v/log_decay (B, S, H, hd); u (H, hd); s0 (B, H, hd, hd) or None.
+    Returns (y (B, S, H, hd), final state (B, H, hd, hd)), as
+    :func:`wkv6_scan` does with w = exp(log_decay).
+
+    With L_t the log-decay summed from the start of the chunk through t,
+    token t of a chunk reads
+        y_t = (r_t * exp(L_{t-1})) S_prev
+              + sum_{s<t} [sum_i r_ti k_si exp(L_{t-1,i} - L_{s,i})] v_s
+              + (r_t . u k_t) v_t
+    and the chunk leaves
+        S = S_prev * exp(L_Q) + sum_s (k_s * exp(L_Q - L_s)) v_s^T.
+    The chunk length Q follows from S (:func:`_wkv_chunk`); a ragged tail
+    is padded with k = v = 0 and log-decay 0, which leaves every earlier
+    output and the final state as they were.
+
+    Inside a chunk the pairs (t, s) split by sub-chunk of WKV_SUBCHUNK
+    tokens:
+    - s and t in one sub-chunk: the exact three-way product over
+      (q, q, hd), its exponent masked to -inf for s >= t before the exp;
+    - s in an earlier sub-chunk than t's sub-chunk j, whose log-decay
+      starts at b_j: (r_t * exp(L_{t-1} - b_j)) . (k_s * exp(b_j - L_s)),
+      a matmul. L_{t-1} <= b_j <= L_s, so both exponents are <= 0.
+    Every exponent is <= 0, so nothing overflows however strong the decay,
+    and a factor that underflows stands for a product that does too. Each
+    exponent sums only the log-decays between its two ends (prefix and
+    suffix sums inside a sub-chunk, plus whole sub-chunks), never the
+    difference of two long cumulative sums, to keep f32 cancellation
+    small; log-decays are floored at LOG_DECAY_FLOOR, below which f32's
+    exp is 0 anyway. The chunks' states pass in one scan of S/Q steps;
+    every contraction is f32 at HIGHEST."""
     bsz, s, h, hd = r.shape
-    chunk = min(chunk, s)
-    if s % chunk:
-        raise ValueError(f"seq {s} % rwkv chunk {chunk}")
-    nc = s // chunk
+    q = WKV_SUBCHUNK
+    chunk = _wkv_chunk(s)
+    nc = -(-s // chunk)
+    ns = chunk // q
     if s0 is None:
         s0 = jnp.zeros((bsz, h, hd, hd), jnp.float32)
+    mm = functools.partial(jnp.einsum, precision=HIGHEST)
 
-    f32 = lambda t: t.astype(jnp.float32)
-    shp = (bsz, nc, chunk, h, hd)
-    rc, kc, vc = (f32(t).reshape(shp) for t in (r, k, v))
-    ld = f32(log_decay).reshape(shp)
-    lc = jnp.cumsum(ld, axis=2)                     # L_t = sum_{s<=t} log w_s
-    lcm1 = lc - ld                                  # L_{t-1}
-    lq = lc[:, :, -1:]                              # L_Q (chunk total)
+    def blocks(t):                                  # (B,S,H,hd) ->
+        t = t.astype(jnp.float32)                   # (B,H,nc,ns,q,hd)
+        t = jnp.pad(t, ((0, 0), (0, nc * chunk - s), (0, 0), (0, 0)))
+        return jnp.moveaxis(t.reshape(bsz, nc, ns, q, h, hd), 4, 1)
 
-    tri = jnp.tril(jnp.ones((chunk, chunk), bool), k=-1)   # s < t
+    rb, kb, vb, ld = (blocks(t) for t in (r, k, v, log_decay))
+    ld = jnp.maximum(ld, LOG_DECAY_FLOOR)
+    # every log-decay sum below adds only the terms between its two ends
+    zero = jnp.zeros_like(ld[:, :, :, :, :1])
+    lsub = jnp.cumsum(ld, axis=4)                   # p <= t, in t's sub-chunk
+    lsub_ex = jnp.concatenate([zero, lsub[:, :, :, :, :-1]], axis=4)  # p < t
+    rsub_ex = jnp.concatenate(                      # p > s, in s's sub-chunk
+        [jnp.cumsum(ld[:, :, :, :, :0:-1], axis=4)[:, :, :, :, ::-1], zero],
+        axis=4)
+    tot = lsub[:, :, :, :, -1]                      # (B,H,nc,ns,hd)
+    tot_cum = jnp.cumsum(tot, axis=3)
+    lq = tot_cum[:, :, :, -1]                       # L_Q, (B,H,nc,hd)
+    b = jnp.concatenate([jnp.zeros_like(tot[:, :, :, :1]),
+                         tot_cum[:, :, :, :-1]], axis=3)  # b_j: before j
+    # span[j, m]: the whole sub-chunks strictly between m and j (j = ns: the
+    # chunk's end), so b_j - L_s = span[j, m] + rsub_ex[s] for s in m < j
+    j, m, n = np.ogrid[:ns + 1, :ns, :ns]
+    between = jnp.asarray((m < n) & (n < j))[..., None]
+    span = jnp.sum(jnp.where(between, tot[:, :, :, None, None], 0.0), axis=5)
 
-    # Intra-chunk quadratic form, streamed over (head x channel-block) so the
-    # (Q, Q, hd_block) decay tensor never exceeds a small VMEM-sized tile.
-    # y = (sum_i A_i) v decomposes as sum over channel blocks of (A_blk v).
-    hd_blk = min(8, hd)
-    nblk = hd // hd_blk
+    # s < t in one sub-chunk: (q, q, hd) product, masked before the exp
+    tri = jnp.tril(jnp.ones((q, q), bool), k=-1)[:, :, None]
+    diff = lsub_ex[:, :, :, :, :, None] - lsub[:, :, :, :, None]
+    decay = jnp.exp(jnp.where(tri, diff, -jnp.inf))
+    a_diag = jnp.sum(rb[:, :, :, :, :, None] * kb[:, :, :, :, None] * decay,
+                     axis=-1)                       # (B,H,nc,ns,q,q)
+    y = mm("bhcnts,bhcnsj->bhcntj", a_diag, vb)
+    # the bonus term (r_t . u k_t) v_t
+    bonus = jnp.sum(rb * u.astype(jnp.float32)[None, :, None, None, None] * kb,
+                    axis=-1, keepdims=True)
+    y = y + bonus * vb
 
-    def blocked(t):                                 # (B,nc,Q,H,hd) ->
-        t = t.reshape(bsz, nc, chunk, h, nblk, hd_blk)
-        return jnp.moveaxis(t, (3, 4), (0, 1)).reshape(
-            h * nblk, bsz, nc, chunk, hd_blk)
+    # s in sub-chunk m < j = t's: k scaled by exp(b_j - L_s) for each
+    # query sub-chunk j, zero for m >= j; r by exp(L_{t-1} - b_j)
+    earlier = jnp.tril(jnp.ones((ns, ns), bool), k=-1)[:, :, None, None]
+    k_exp = span[:, :, :, :ns, :, None] + rsub_ex[:, :, :, None]
+    k_off = kb[:, :, :, None] * jnp.exp(
+        jnp.where(earlier, k_exp, -jnp.inf))        # (B,H,nc,j,m,q,hd)
+    a_off = mm("bhcjti,bhcjmsi->bhcjtms", rb * jnp.exp(lsub_ex), k_off)
+    y = y + mm("bhcjtms,bhcmsk->bhcjtk", a_off, vb)
 
-    v_rep = jnp.broadcast_to(jnp.moveaxis(vc, 3, 0)[:, None],
-                             (h, nblk, bsz, nc, chunk, hd)
-                             ).reshape(h * nblk, bsz, nc, chunk, hd)
-
-    def per_block(args):
-        rh, kh, lch, lcm1h, vh = args               # (B, nc, Q, hd_blk)
-        # A[t,s] = sum_i r_t k_s exp(L_{t-1} - L_s), s < t   (exponent <= 0)
-        diff = lcm1h[:, :, :, None, :] - lch[:, :, None, :, :]
-        diff = jnp.where(tri[None, None, :, :, None], diff, -jnp.inf)
-        a = jnp.einsum("bcti,bcsi,bctsi->bcts", rh, kh, jnp.exp(diff))
-        return jnp.einsum("bcts,bcsj->bctj", a, vh)
-
-    parts = maybe_map(per_block,
-                      (blocked(rc), blocked(kc), blocked(lc),
-                       blocked(lcm1), v_rep), unroll)
-    parts = parts.reshape(h, nblk, bsz, nc, chunk, hd).sum(axis=1)
-    y_intra = jnp.moveaxis(parts, 0, 3)
-
-    # bonus (diagonal) term: (r_t . u k_t) v_t
-    bonus = jnp.einsum("bcthi,hi,bcthi->bcth", rc, u.astype(jnp.float32), kc)
-    y_intra = y_intra + bonus[..., None] * vc
-
-    # inter-chunk: state scan, one (hd, hd) read/write per chunk
-    r_tilde = rc * jnp.exp(lcm1)                    # exponent <= 0
-    k_hat = kc * jnp.exp(lq - lc)                   # exponent <= 0
-    chunk_states = jnp.einsum("bcthi,bcthj->bchij", k_hat, vc)
-    chunk_decay = jnp.exp(lq[:, :, 0])              # (B, nc, H, hd)
+    # the chunks' states: one read and one write of (hd, hd) per chunk
+    k_hat = kb * jnp.exp(span[:, :, :, ns, :, None] + rsub_ex)  # L_Q - L_s
+    chunk_states = mm("bhcnsi,bhcnsj->bhcij", k_hat, vb)
 
     def step(carry, inp):
-        st, dcy = inp                               # (B,H,hd,hd), (B,H,hd)
-        new = carry * dcy[..., None] + st
-        return new, carry                           # emit state BEFORE chunk
+        st, lq_c = inp                              # (B,H,hd,hd), (B,H,hd)
+        return carry * jnp.exp(lq_c)[..., None] + st, carry  # state BEFORE
 
-    sw = lambda t: jnp.moveaxis(t, 1, 0)
-    s_final, s_prev = maybe_scan(step, s0,
-                                 (sw(chunk_states), sw(chunk_decay)),
+    sw = lambda t: jnp.moveaxis(t, 2, 0)
+    s_final, s_prev = maybe_scan(step, s0, (sw(chunk_states), sw(lq)),
                                  unroll=unroll)
-    s_prev = jnp.moveaxis(s_prev, 0, 1)             # (B,nc,H,hd,hd)
-    y_state = jnp.einsum("bcthi,bchij->bcthj", r_tilde, s_prev)
-    y = (y_intra + y_state).reshape(bsz, s, h, hd)
+    r_state = rb * jnp.exp(b[:, :, :, :, None] + lsub_ex)      # L_{t-1}
+    y = y + mm("bhcnti,cbhij->bhcntj", r_state, s_prev)
+    y = jnp.moveaxis(y, 1, 4).reshape(bsz, nc * chunk, h, hd)[:, :s]
     return y, s_final
 
 
@@ -189,33 +234,27 @@ def _tm_output(params, y, g, d_model):
     return shard_hint(out, "batch", "seq", None)
 
 
-def rwkv6_timemix_forward(params, x, headdim: int = 64, chunk: int = 0,
+def rwkv6_timemix_forward(params, x, headdim: int = 64,
                           unroll: bool = False):
-    out, _ = rwkv6_timemix_forward_state(params, x, headdim, chunk,
-                                         unroll=unroll)
+    out, _ = rwkv6_timemix_forward_state(params, x, headdim, unroll=unroll)
     return out
 
 
 def rwkv6_timemix_forward_state(params, x, headdim: int = 64,
-                                chunk: int = 0, unroll: bool = False):
-    """Full-sequence time-mix that also returns the decode cache.
-    chunk == 0 -> per-token lax.scan (baseline); chunk > 0 -> chunk-parallel
-    WKV6 (§Perf optimization)."""
+                                unroll: bool = False):
+    """Full-sequence time-mix, through the chunk-parallel WKV6, that also
+    returns the decode cache."""
     d_model = x.shape[-1]
     n_heads = d_model // headdim
     x_prev = _token_shift(x)
-    r, k, v, g, w, log_decay = _tm_inputs(params, x, x_prev)
+    r, k, v, g, _, log_decay = _tm_inputs(params, x, x_prev)
     heads = lambda t: t.reshape(t.shape[0], t.shape[1], n_heads, headdim)
     # the recurrence's ops (and, through the transpose, their backward)
     # carry this scope in the profiler trace
     with jax.named_scope(WKV_SCAN_SCOPE):
-        if chunk:
-            y, s_final = wkv6_chunked(heads(r), heads(k), heads(v),
-                                      heads(log_decay), params["bonus_u"],
-                                      chunk=chunk, unroll=unroll)
-        else:
-            y, s_final = wkv6_scan(heads(r), heads(k), heads(v), heads(w),
-                                   params["bonus_u"], unroll=unroll)
+        y, s_final = wkv6_chunked(heads(r), heads(k), heads(v),
+                                  heads(log_decay), params["bonus_u"],
+                                  unroll=unroll)
     out = _tm_output(params, y.astype(x.dtype), g, d_model)
     return out, {"wkv": s_final, "tm_last": x[:, -1:]}
 

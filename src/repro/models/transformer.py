@@ -250,7 +250,6 @@ class Transformer:
         if spec.mixer == "rwkv6":
             return rwkv_mod.rwkv6_timemix_forward(lparams["mixer"], h,
                                                   cfg.rwkv_headdim,
-                                                  cfg.rwkv_chunk,
                                                   unroll=cfg.scan_unroll)
         raise ValueError(spec.mixer)
 
@@ -708,7 +707,7 @@ class Transformer:
             new_cache["mixer"] = st
         elif spec.mixer == "rwkv6":
             out, st = rwkv_mod.rwkv6_timemix_forward_state(
-                lparams["mixer"], h, cfg.rwkv_headdim, cfg.rwkv_chunk)
+                lparams["mixer"], h, cfg.rwkv_headdim)
             new_cache["mixer"] = st
         else:
             raise ValueError(spec.mixer)

@@ -49,28 +49,97 @@ def test_bucketed_causal_equals_full_grid(sq):
                                atol=2e-6)
 
 
-def test_wkv6_chunked_equals_scan_gradients():
-    """Forward AND gradients match (the chunked form is used in training)."""
-    b, s, h, hd = 1, 32, 2, 16
-    ks = jax.random.split(jax.random.PRNGKey(0), 5)
+# per-token log-decay -exp(x), x uniform in a range: the benchmark cells'
+# (w0 in [-6, -1]), strong ones down to -30 a token, extreme down to -4e4
+WKV_DECAYS = {"mild": (-6.0, -1.0), "strong": (-6.0, 3.4),
+              "extreme": (-6.0, 10.6)}
+
+
+def _wkv_both(s, decay):
+    """Outputs and gradients (in r, k, v, log-decay, u and s0) of the
+    per-token recurrence and of the chunked form, B = 2, a nonzero s0."""
+    b, h, hd = 2, 2, 16
+    ks = jax.random.split(jax.random.PRNGKey(s), 8)
     r, k, v = (jax.random.normal(kk, (b, s, h, hd)) for kk in ks[:3])
-    logw = -jnp.exp(jax.random.normal(ks[3], (b, s, h, hd)) - 2)
-    u = jax.random.normal(ks[4], (h, hd))
+    lo, hi = WKV_DECAYS[decay]
+    logw = -jnp.exp(jax.random.uniform(ks[3], (b, s, h, hd), minval=lo,
+                                       maxval=hi))
+    u = 0.5 * jax.random.normal(ks[4], (h, hd))
+    s0 = jax.random.normal(ks[5], (b, h, hd, hd))
+    wy = jax.random.normal(ks[6], (b, s, h, hd))
+    ws = jax.random.normal(ks[7], (b, h, hd, hd))
 
-    def f_scan(r):
-        y, _ = wkv6_scan(r, k, v, jnp.exp(logw), u)
-        return jnp.sum(y ** 2)
+    def run(chunked):
+        def f(r, k, v, logw, u, s0):
+            if chunked:
+                return wkv6_chunked(r, k, v, logw, u, s0)
+            return wkv6_scan(r, k, v, jnp.exp(logw), u, s0)
 
-    def f_chunk(r):
-        y, _ = wkv6_chunked(r, k, v, logw, u, chunk=8)
-        return jnp.sum(y ** 2)
+        def obj(*args):
+            y, st = f(*args)
+            return jnp.sum(y * wy) + jnp.sum(st * ws)
 
-    np.testing.assert_allclose(float(f_scan(r)), float(f_chunk(r)),
-                               rtol=1e-4)
-    g1 = jax.grad(f_scan)(r)
-    g2 = jax.grad(f_chunk)(r)
-    np.testing.assert_allclose(np.asarray(g1), np.asarray(g2), rtol=1e-3,
-                               atol=1e-4)
+        args = (r, k, v, logw, u, s0)
+        return (*f(*args), *jax.grad(obj, argnums=tuple(range(6)))(*args))
+
+    return run(chunked=False), run(chunked=True)
+
+
+@pytest.mark.parametrize("decay", ["mild", "strong"])
+@pytest.mark.parametrize("s", [1, 16, 64, 96, 256])
+def test_wkv6_chunked_equals_scan_gradients(s, decay):
+    """The chunked form (used in training and prefill) matches the
+    per-token recurrence: output, final state, and the gradients in r, k,
+    v, log-decay, u and s0, all finite. S = 96 leaves a ragged tail chunk,
+    S = 16 a single sub-chunk, S = 1 (a one-token prefill) a padded one."""
+    for want, got in zip(*_wkv_both(s, decay)):
+        assert np.all(np.isfinite(np.asarray(got)))
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-3, atol=1e-4)
+
+
+@pytest.mark.parametrize("s", [16, 64, 96, 256])
+def test_wkv6_chunked_extreme_decay_finite(s):
+    """Log-decays down to -4e4 a token: every output and gradient stays
+    finite and near the recurrence's. Each in-chunk exponent carries the
+    f32 rounding of a sub-chunk's summed log-decay (16 tokens, each
+    floored at LOG_DECAY_FLOOR), so the match is to about 1e-4 of the
+    largest value rather than to the mild cases' 1e-4 absolute."""
+    for want, got in zip(*_wkv_both(s, "extreme")):
+        got, want = np.asarray(got), np.asarray(want)
+        assert np.all(np.isfinite(got))
+        np.testing.assert_allclose(got, want, rtol=1e-3,
+                                   atol=1e-4 * np.abs(want).max())
+
+
+def test_rwkv6_transformer_chunked_matches_per_token(monkeypatch):
+    """An rwkv6 Transformer's loss and gradient through the chunked WKV
+    equal those through the per-token recurrence (the oracle, patched in
+    for this test only), at a small width and a ragged S = 40."""
+    import dataclasses
+
+    from repro.configs import get_arch, smoke_variant
+    from repro.models import rwkv as rwkv_mod
+    from repro.models.transformer import Transformer
+
+    cfg = dataclasses.replace(smoke_variant(get_arch("rwkv6-1.6b")),
+                              dtype="float32")
+    model = Transformer(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    toks = jax.random.randint(jax.random.PRNGKey(1), (2, 41), 0, cfg.vocab)
+    batch = {"tokens": toks[:, :-1], "labels": toks[:, 1:]}
+    loss, grads = jax.value_and_grad(model.loss_fn)(params, batch)
+
+    def per_token(r, k, v, log_decay, u, s0=None, unroll=False):
+        return wkv6_scan(r, k, v, jnp.exp(log_decay), u, s0, unroll=unroll)
+
+    monkeypatch.setattr(rwkv_mod, "wkv6_chunked", per_token)
+    loss_ref, grads_ref = jax.value_and_grad(model.loss_fn)(params, batch)
+    np.testing.assert_allclose(float(loss), float(loss_ref), rtol=1e-5)
+    for got, want in zip(jax.tree.leaves(grads), jax.tree.leaves(grads_ref)):
+        want = np.asarray(want)
+        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-3,
+                                   atol=1e-4 * np.abs(want).max() + 1e-12)
 
 
 def test_moe_scatter_equals_dense_train_and_decode():
